@@ -5,28 +5,27 @@ from __future__ import annotations
 import time
 from typing import Callable, Iterable
 
+import jax
 import numpy as np
 
 
 def time_call(fn: Callable, *, warmup: int = 1, iters: int = 3) -> float:
     """Median wall seconds per call (after warmup, block_until_ready-safe)."""
     for _ in range(warmup):
-        _block(fn())
+        jax.block_until_ready(fn())
     times = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        _block(fn())
+        jax.block_until_ready(fn())
         times.append(time.perf_counter() - t0)
     return float(np.median(times))
 
 
-def _block(x):
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except Exception:
-        pass
-    return x
+def device_info() -> dict:
+    """The device a benchmark's numbers come from, as JAX reports it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "count": len(jax.devices())}
 
 
 def emit(name: str, seconds: float, derived: str = "") -> str:

@@ -22,7 +22,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, series_to_csv, time_call
+from benchmarks.common import device_info, emit, series_to_csv, time_call
 from repro.core.engine import simulate
 from repro.core.jobs import POLICY_IDS, make_jobset
 from repro.kernels.queue_select.ops import queue_select
@@ -81,12 +81,13 @@ def _galactic_jobs(tiles: int, width: int, total_nodes: int):
 
 def run_bench(outdir: str = "results", *, smoke: bool = False) -> dict:
     os.makedirs(outdir, exist_ok=True)
-    # schema 3: queue_select cases are timed compiled and carry
-    # bytes/tile/mode so GB/s figures are comparable across cases
-    # (schema 2 added generated_unix/finished_unix); pinned by
+    # schema 4: the report names the device that took its numbers
+    # (schema 3: queue_select cases are timed compiled and carry
+    # bytes/tile/mode so GB/s figures are comparable across cases;
+    # schema 2 added generated_unix/finished_unix); pinned by
     # tests/test_bench_schema.py — bump the version when keys change
-    report: dict = {"schema": 3, "smoke": smoke, "cases": {},
-                    "generated_unix": time.time()}
+    report: dict = {"schema": 4, "smoke": smoke, "cases": {},
+                    "generated_unix": time.time(), "device": device_info()}
 
     # ---- no-deps policy throughput on the SDSC-SP2-like trace --------------
     J = 200 if smoke else 2000
@@ -222,7 +223,7 @@ def run_bench(outdir: str = "results", *, smoke: bool = False) -> dict:
     path = os.path.join(outdir, BENCH_JSON)
     with open(path, "w") as f:
         json.dump(report, f, indent=1, sort_keys=True)
-    print(f"# wrote {path}", flush=True)
+    print(f"# wrote {path} (device: {report['device']})", flush=True)
     return report
 
 

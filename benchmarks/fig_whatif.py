@@ -52,7 +52,7 @@ def _queries(smoke: bool):
 
 def _run(smoke: bool, outdir: str = "results") -> None:
     os.makedirs(outdir, exist_ok=True)
-    report = {"schema": 1, "smoke": smoke,
+    report = {"schema": 1, "smoke": smoke, "device": common.device_info(),
               "generated_unix": time.time(), "cases": {}}
     families = _queries(smoke)
 

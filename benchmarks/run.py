@@ -22,6 +22,7 @@ from benchmarks import (
     fig_reliability, fig_serving, fig_whatif, fig_workflow_cluster,
     roofline_table,
 )
+from repro.compile_cache import enable_compile_cache
 
 BENCHES = [
     ("fig3_occupancy", fig3_occupancy),
@@ -46,6 +47,7 @@ def main() -> int:
     smoke = "--smoke" in args
     args = [a for a in args if a != "--smoke"]
     pattern = args[0] if args else ""
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name, mod in BENCHES:

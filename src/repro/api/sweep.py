@@ -297,8 +297,14 @@ def _bucket_fn(with_alloc: bool, with_fail: bool, with_svc: bool,
     return jax.jit(fn, out_shardings=NamedSharding(mesh, P(axis)))
 
 
-def _run_bucket(bucket: List[Scenario], mesh: Optional[Mesh]) -> List[Result]:
-    """vmap-batch all scenarios of one static bucket (single-cluster only)."""
+def _bucket_program(bucket: List[Scenario], mesh: Optional[Mesh]):
+    """The executable key and host arguments of one static bucket.
+
+    Returns ``(fn_key, args, machine, jobsets)``: ``_bucket_fn(*fn_key)``
+    is the batched runner, called as ``fn(*args)`` or ``fn(*args,
+    machine)``; ``jobsets[i]`` is point *i*'s job table.  Split from
+    ``_run_bucket`` so the same program can be lowered from shapes alone.
+    """
     base = bucket[0]
     machine = base.topology.build() if base.topology is not None else None
     max_events = base.max_events
@@ -391,9 +397,16 @@ def _run_bucket(bucket: List[Scenario], mesh: Optional[Mesh]) -> List[Result]:
     axis = mesh.axis_names[0] if mesh is not None else None
     fn_key = (machine is not None, with_fail, with_svc, with_mal,
               max_events, mesh, axis, static_pol, static_alloc)
+    return fn_key, args, machine, jobsets
+
+
+def _run_bucket(bucket: List[Scenario], mesh: Optional[Mesh]) -> List[Result]:
+    """vmap-batch all scenarios of one static bucket (single-cluster only)."""
+    fn_key, args, machine, jobsets = _bucket_program(bucket, mesh)
     fn = _bucket_fn(*fn_key)
     _log_bucket_execution(fn_key, args, machine)
     if mesh is not None:
+        axis = mesh.axis_names[0]
         shard = NamedSharding(mesh, P(axis))
         args = tuple(jax.device_put(a, shard) for a in args)
     batched = fn(*args) if machine is None else fn(*args, machine)

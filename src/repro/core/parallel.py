@@ -406,13 +406,12 @@ def simulate_multicluster(
         )(jobs_c, nodes_c)
     else:
         axis = mesh.axis_names[0]
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda j, n: local_sim(j, n, axis),
             mesh=mesh,
             in_specs=(P(axis), P(axis)),
             out_specs=P(axis),
-            check_rep=False,
+            check_vma=False,
         )
         jobs, state, mig, drop, sat = jax.jit(fn)(jobs_c, nodes_c)
 

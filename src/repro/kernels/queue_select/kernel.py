@@ -95,7 +95,9 @@ def queue_select_tiled(scores: jax.Array, feasible: jax.Array, *,
             pl.BlockSpec((1, tile), lambda t: (0, t)),
             pl.BlockSpec((1, tile), lambda t: (0, t)),
         ],
-        out_specs=pl.BlockSpec((1, 2), lambda t: (0, 0)),
+        # the (index, score) pair is two scalars: TPU stores scalars only
+        # to SMEM, so the whole (1, 2) output lives there
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 2), jnp.int32),
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.int32),
                         pltpu.SMEM((1, 1), jnp.int32)],

@@ -8,29 +8,21 @@ from __future__ import annotations
 
 import jax
 
-# jax.sharding.AxisType landed after 0.4.x; on older jax every mesh axis is
-# implicitly Auto, so omitting axis_types is the exact equivalent.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_local_mesh(axes=("data", "model")):
     """All local devices on the first axis (CPU tests / examples)."""
     n = len(jax.devices())
     shape = (n,) + (1,) * (len(axes) - 1)
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def mesh_num_devices(mesh) -> int:

@@ -12,6 +12,7 @@ import argparse
 import json
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.replay.runner import StreamingReplay
 from repro.traces.swf import load_swf
 
@@ -44,6 +45,7 @@ def main(argv=None) -> int:
 
     if args.resume and args.ckpt_dir is None:
         ap.error("--resume requires --ckpt-dir")
+    enable_compile_cache()
     trace, report = load_swf(args.trace, max_jobs=args.max_jobs,
                              strict=args.strict)
     print(report.summary(), file=sys.stderr)

@@ -29,6 +29,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional, Tuple
 
 from repro.api import FailureModel, Scenario, SyntheticTrace, Topology
+from repro.compile_cache import enable_compile_cache
 
 from repro.service.planner import CapacityPlanner, UnknownQueueError
 from repro.service.query import (
@@ -158,6 +159,7 @@ def main(argv: Optional[list] = None) -> int:
     parser.add_argument("--verbose", action="store_true",
                         help="log every request")
     args = parser.parse_args(argv)
+    enable_compile_cache()
 
     if args.demo:
         fleet = demo_fleet()

@@ -5,17 +5,17 @@ The reliability subsystem must *statically elide* to nothing: a
 pre-reliability engine produced — not just the same results, the same
 compiled program.  This module pins that: ``fingerprints()`` lowers the
 engine across the existing policy × alloc × DAG differential grid and
-hashes the StableHLO text; ``tests/data/hlo_nofail.json`` holds the hashes
-recorded at the commit *before* the reliability changes, and
-``tests/test_engine_fastpath.py`` asserts today's lowering still matches.
+hashes the StableHLO text; ``tests/data/hlo_nofail.json`` holds the pinned
+hashes, and ``tests/test_engine_fastpath.py`` asserts today's lowering
+still matches.
 
 Regenerate (only when an *intentional* engine-graph change lands)::
 
     PYTHONPATH=src:tests python tests/_hlo_fixture.py --write
 
 Hashes are stable across processes for a fixed jax version; the fixture
-records the jax version it was built with so a toolchain bump skips (not
-fails) the comparison.
+records the jax version it was built with, and a toolchain bump fails the
+comparison with the command above, so the pin never lapses in silence.
 """
 
 from __future__ import annotations

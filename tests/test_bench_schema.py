@@ -5,6 +5,8 @@ machine-readable perf trajectory future PRs regress against.  A benchmark
 refactor that silently changes keys or units would corrupt that trajectory
 without failing anything; these tests pin the schema:
 
+- every report names the device that took its numbers (``platform``,
+  ``device_kind``, ``count``), so a CPU number is never read as a chip's;
 - every case carries a positive ``run_s``; engine cases carry ``n_events``
   / ``events_per_s`` / ``compile_s`` that are mutually consistent;
 - wall-clock stamps are present and monotonic (schema >= 2);
@@ -30,6 +32,10 @@ RESULTS_JSON = os.path.join(os.path.dirname(__file__), "..", "results",
 def validate_bench_report(report: dict) -> None:
     assert isinstance(report.get("schema"), int) and report["schema"] >= 1
     assert isinstance(report.get("smoke"), bool)
+    dev = report.get("device")
+    assert isinstance(dev, dict) and dev.get("platform") and \
+        dev.get("device_kind") and dev.get("count", 0) >= 1, \
+        "report does not name the device that took its numbers"
     cases = report.get("cases")
     assert isinstance(cases, dict) and cases, "report carries no cases"
     for name, case in cases.items():

@@ -71,8 +71,8 @@ def _run_step(argv, env, step: str) -> subprocess.CompletedProcess:
 
 @pytest.mark.timeout(2 * SUBPROC_TIMEOUT + 60)
 def test_elastic_restore_across_device_counts(tmp_path):
-    env = {**os.environ, "PYTHONPATH": "src"}
-    env.pop("JAX_PLATFORMS", None)
+    # the child rehearses on virtual CPU devices and must never take a chip
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
     ck = str(tmp_path / "ck")
     p1 = _run_step([sys.executable, "-c", _SAVE, ck], env, "save")
     assert "SAVED 4" in p1.stdout, p1.stderr[-800:]

@@ -44,8 +44,8 @@ _CHILD = textwrap.dedent("""
 
 @pytest.mark.timeout(600)
 def test_multicluster_sharded_matches_single_device(tmp_path):
-    env = {**os.environ, "PYTHONPATH": "src"}
-    env.pop("JAX_PLATFORMS", None)
+    # the child rehearses on virtual CPU devices and must never take a chip
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
     p = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                        capture_output=True, text=True, timeout=540)
     assert "SHARDED_OK" in p.stdout, (p.stdout[-400:], p.stderr[-800:])

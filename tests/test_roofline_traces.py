@@ -95,12 +95,8 @@ def test_analytic_bytes_monotone_in_params():
 
 
 def test_repair_pspec_moves_uneven_axis():
-    # jax.sharding.AxisType is absent on jax 0.4.x, where every axis is
-    # implicitly Auto — construct the mesh the version-appropriate way
-    # (mirrors repro.launch.mesh._mesh_kwargs)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {} if axis_type is None else {"axis_types": (axis_type.Auto,) * 2}
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **kwargs)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     class FakeMesh:
         shape = {"data": 16, "model": 16}
