@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import metrics
 from repro.core.jobs import JobSet, SimResult
 
@@ -57,12 +58,15 @@ class Result:
     def _materialize_np(self) -> Dict[str, np.ndarray]:
         if self.backend == "ref":
             return dict(self.raw)
-        if self.backend == "multicluster":
-            from repro.core.parallel import multicluster_result_np
-            return multicluster_result_np(self.raw)
-        return simresult_to_np(self.raw, self.jobs,
-                               with_alloc=self.scenario.topology is not None,
-                               service=self._service_plan())
+        # the device-to-host transfer: the host blocks on the device here
+        with obs.span("result.fetch"):
+            if self.backend == "multicluster":
+                from repro.core.parallel import multicluster_result_np
+                return multicluster_result_np(self.raw)
+            return simresult_to_np(
+                self.raw, self.jobs,
+                with_alloc=self.scenario.topology is not None,
+                service=self._service_plan())
 
     def _service_plan(self):
         spec = self.scenario.trace_specs()[0]
@@ -77,21 +81,22 @@ class Result:
         """Scalar metrics: n_jobs, wait stats, bounded slowdown, makespan,
         utilization, throughput — plus job-span/fragmentation scalars when
         the scenario carried a topology."""
-        out = self.to_np()
-        total = int(np.sum(self.scenario.nodes_per_cluster()))
-        s = metrics.summary(out, total)
-        if "ev_time" in out and "alloc_span" in out:
-            s.update(metrics.alloc_summary(out))
-        if "n_restarts" in out:
-            s.update(metrics.reliability_summary(out))
-        if "slo_met" in out:
-            plan = self._service_plan()
-            names = plan.class_names if plan is not None else None
-            s.update(metrics.slo_summary(out, class_names=names,
-                                         total_nodes=total))
-        if "mal_width" in out:
-            s.update(metrics.malleable_summary(out))
-        return s
+        with obs.span("result.summary"):
+            out = self.to_np()
+            total = int(np.sum(self.scenario.nodes_per_cluster()))
+            s = metrics.summary(out, total)
+            if "ev_time" in out and "alloc_span" in out:
+                s.update(metrics.alloc_summary(out))
+            if "n_restarts" in out:
+                s.update(metrics.reliability_summary(out))
+            if "slo_met" in out:
+                plan = self._service_plan()
+                names = plan.class_names if plan is not None else None
+                s.update(metrics.slo_summary(out, class_names=names,
+                                             total_nodes=total))
+            if "mal_width" in out:
+                s.update(metrics.malleable_summary(out))
+            return s
 
     @property
     def makespan(self) -> int:

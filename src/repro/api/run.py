@@ -17,6 +17,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 from repro import alloc as _alloc
+from repro import obs
 from repro.core import engine
 from repro.core.jobs import JobSet, POLICY_NAMES, make_jobset
 from repro.core.parallel import simulate_multicluster, stack_jobsets
@@ -92,22 +93,28 @@ def _mal_plan(scenario: Scenario):
 
 
 def run(scenario: Scenario) -> Result:
-    """Run one scenario on the JAX engine and return a unified ``Result``."""
+    """Run one scenario on the JAX engine and return a unified ``Result``.
+
+    Host spans: ``run.prepare`` (job table and contexts) and
+    ``run.dispatch`` (the engine call)."""
     if scenario.multicluster is not None:
         return _run_multicluster(scenario)
-    jobs = build_jobset(scenario)
-    res = engine.simulate(
-        jobs,
-        engine.policies_id(scenario.policy),
-        int(scenario.total_nodes),
-        machine=_machine(scenario),
-        alloc=scenario.alloc,
-        contention=scenario.contention,
-        failures=_failure_trace(scenario),
-        service=_service_plan(scenario),
-        malleable=_mal_plan(scenario),
-        max_events=scenario.max_events,
-    )
+    with obs.span("run.prepare"):
+        jobs = build_jobset(scenario)
+        contexts = dict(machine=_machine(scenario),
+                        failures=_failure_trace(scenario),
+                        service=_service_plan(scenario),
+                        malleable=_mal_plan(scenario))
+    with obs.span("run.dispatch"):
+        res = engine.simulate(
+            jobs,
+            engine.policies_id(scenario.policy),
+            int(scenario.total_nodes),
+            alloc=scenario.alloc,
+            contention=scenario.contention,
+            max_events=scenario.max_events,
+            **contexts,
+        )
     return Result(scenario=scenario, backend="jax", raw=res, jobs=jobs)
 
 

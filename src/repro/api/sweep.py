@@ -45,6 +45,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import alloc as _alloc
+from repro import obs
 from repro.core import engine
 from repro.core.jobs import JobSet
 from repro.core.parallel import stack_jobsets
@@ -188,11 +189,11 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence[Any]], *,
 # the batched argument treedef and leaf shapes/dtypes, i.e. exactly what
 # determines whether jit reuses an executable or compiles a new one.  A
 # signature seen before counts as a `hit` (warm), a new one as a `compile`
-# (cold).  Stats cover the batched bucket runners only: `sweep(s, axes={})`
-# degenerates to `run()` and multicluster buckets run point-wise, neither
-# of which goes through the shared executable cache.
+# (cold); both are counters of `repro.obs` (`sweep.compiles`,
+# `sweep.hits`).  Stats cover the batched bucket runners only:
+# `sweep(s, axes={})` degenerates to `run()` and multicluster buckets run
+# point-wise, neither of which goes through the shared executable cache.
 
-_CACHE_LOG = {"compiles": 0, "hits": 0}
 _SEEN_SIGNATURES: set = set()
 
 
@@ -214,8 +215,9 @@ class SweepCacheStats:
 
 def cache_stats() -> SweepCacheStats:
     """Current warm-vs-cold counters for the sweep executable cache."""
-    return SweepCacheStats(compiles=_CACHE_LOG["compiles"],
-                           hits=_CACHE_LOG["hits"],
+    c = obs.counters()
+    return SweepCacheStats(compiles=c.get("sweep.compiles", 0),
+                           hits=c.get("sweep.hits", 0),
                            entries=len(_SEEN_SIGNATURES))
 
 
@@ -229,25 +231,24 @@ def reset_cache_stats(*, clear: bool = False) -> None:
     (``_bucket_fn.cache_clear()``) and the signature set, so the next query
     genuinely recompiles — the cold-path fixture for benchmarks and tests.
     """
-    _CACHE_LOG["compiles"] = 0
-    _CACHE_LOG["hits"] = 0
+    obs.reset_counters("sweep.compiles", "sweep.hits")
     if clear:
         _SEEN_SIGNATURES.clear()
         _bucket_fn.cache_clear()
 
 
-def _log_bucket_execution(fn_key: tuple, args: tuple, machine) -> None:
+def _log_bucket_execution(fn_key: tuple, args: tuple, machine) -> bool:
+    """Count one bucket execution; True when its signature is new."""
     leaves, treedef = jax.tree.flatten((args, machine))
     sig = (fn_key, str(treedef),
            tuple((tuple(np.shape(leaf)),
                   np.dtype(getattr(leaf, "dtype",
                                    np.asarray(leaf).dtype)).str)
                  for leaf in leaves))
-    if sig in _SEEN_SIGNATURES:
-        _CACHE_LOG["hits"] += 1
-    else:
-        _SEEN_SIGNATURES.add(sig)
-        _CACHE_LOG["compiles"] += 1
+    cold = sig not in _SEEN_SIGNATURES
+    _SEEN_SIGNATURES.add(sig)
+    obs.count("sweep.compiles" if cold else "sweep.hits")
+    return cold
 
 
 @functools.lru_cache(maxsize=None)
@@ -401,18 +402,30 @@ def _bucket_program(bucket: List[Scenario], mesh: Optional[Mesh]):
 
 
 def _run_bucket(bucket: List[Scenario], mesh: Optional[Mesh]) -> List[Result]:
-    """vmap-batch all scenarios of one static bucket (single-cluster only)."""
-    fn_key, args, machine, jobsets = _bucket_program(bucket, mesh)
-    fn = _bucket_fn(*fn_key)
-    _log_bucket_execution(fn_key, args, machine)
-    if mesh is not None:
-        axis = mesh.axis_names[0]
-        shard = NamedSharding(mesh, P(axis))
-        args = tuple(jax.device_put(a, shard) for a in args)
-    batched = fn(*args) if machine is None else fn(*args, machine)
+    """vmap-batch all scenarios of one static bucket (single-cluster only).
 
-    return [
-        Result(scenario=scn, backend="jax",
-               raw=jax.tree.map(lambda a, i=i: a[i], batched), jobs=jobsets[i])
-        for i, scn in enumerate(bucket)
-    ]
+    Host spans: ``sweep.prepare`` (job tables, contexts, stacking),
+    ``sweep.dispatch`` (signature log and the jitted call; a cold
+    signature compiles here) and ``sweep.split`` (per-lane results)."""
+    lanes = len(bucket)
+    obs.count("sweep.buckets")
+    obs.count("sweep.lanes", lanes)
+    with obs.span("sweep.prepare", lanes=lanes):
+        fn_key, args, machine, jobsets = _bucket_program(bucket, mesh)
+    with obs.span("sweep.dispatch", lanes=lanes) as sp:
+        cold = _log_bucket_execution(fn_key, args, machine)
+        sp.set_metadata(cold=int(cold))
+        fn = _bucket_fn(*fn_key)
+        if mesh is not None:
+            axis = mesh.axis_names[0]
+            shard = NamedSharding(mesh, P(axis))
+            args = tuple(jax.device_put(a, shard) for a in args)
+        batched = fn(*args) if machine is None else fn(*args, machine)
+
+    with obs.span("sweep.split", lanes=lanes):
+        return [
+            Result(scenario=scn, backend="jax",
+                   raw=jax.tree.map(lambda a, i=i: a[i], batched),
+                   jobs=jobsets[i])
+            for i, scn in enumerate(bucket)
+        ]

@@ -235,18 +235,19 @@ def _start_job(jobs: JobSet, state: SimState, idx: jax.Array,
             prev == 0, dur_row[k],
             _ratio_ceil(state.remaining[idx], dur_row[k], dur_row[prev_k]))
         if ctx is not None:
-            machine, strategy, _ = ctx
-            mask = _alloc.place(strategy, machine, _owner_eff(jobs, state),
-                                w)
-            span = _alloc.group_span(machine, mask)
-            first, asum = _alloc.alloc_fingerprint(mask)
-            state = dataclasses.replace(
-                state,
-                node_owner=jnp.where(mask, idx, state.node_owner),
-                alloc_first=state.alloc_first.at[idx].set(first),
-                alloc_span=state.alloc_span.at[idx].set(span),
-                alloc_sum=state.alloc_sum.at[idx].set(asum),
-            )
+            with jax.named_scope("place"):
+                machine, strategy, _ = ctx
+                mask = _alloc.place(strategy, machine,
+                                    _owner_eff(jobs, state), w)
+                span = _alloc.group_span(machine, mask)
+                first, asum = _alloc.alloc_fingerprint(mask)
+                state = dataclasses.replace(
+                    state,
+                    node_owner=jnp.where(mask, idx, state.node_owner),
+                    alloc_first=state.alloc_first.at[idx].set(first),
+                    alloc_span=state.alloc_span.at[idx].set(span),
+                    alloc_sum=state.alloc_sum.at[idx].set(asum),
+                )
         m = state.mal
         state = dataclasses.replace(state, mal=dataclasses.replace(
             m,
@@ -269,19 +270,20 @@ def _start_job(jobs: JobSet, state: SimState, idx: jax.Array,
     if ctx is None:
         dil_rem = state.remaining[idx]
     else:
-        machine, strategy, con = ctx
-        mask = _alloc.place(strategy, machine, _owner_eff(jobs, state),
-                            jobs.nodes[idx])
-        span = _alloc.group_span(machine, mask)
-        first, asum = _alloc.alloc_fingerprint(mask)
-        dil_rem = _alloc.dilate(con, state.remaining[idx], span)
-        state = dataclasses.replace(
-            state,
-            node_owner=jnp.where(mask, idx, state.node_owner),
-            alloc_first=state.alloc_first.at[idx].set(first),
-            alloc_span=state.alloc_span.at[idx].set(span),
-            alloc_sum=state.alloc_sum.at[idx].set(asum),
-        )
+        with jax.named_scope("place"):
+            machine, strategy, con = ctx
+            mask = _alloc.place(strategy, machine, _owner_eff(jobs, state),
+                                jobs.nodes[idx])
+            span = _alloc.group_span(machine, mask)
+            first, asum = _alloc.alloc_fingerprint(mask)
+            dil_rem = _alloc.dilate(con, state.remaining[idx], span)
+            state = dataclasses.replace(
+                state,
+                node_owner=jnp.where(mask, idx, state.node_owner),
+                alloc_first=state.alloc_first.at[idx].set(first),
+                alloc_span=state.alloc_span.at[idx].set(span),
+                alloc_sum=state.alloc_sum.at[idx].set(asum),
+            )
     fin = start + dil_rem
     rsv = start + jobs.estimate[idx]
     first_start = jnp.minimum(state.start[idx], start)
@@ -307,39 +309,42 @@ def _preempt_for(jobs: JobSet, state: SimState, idx: jax.Array,
     ``contiguous`` strategy the subsequent placement may fall back to
     scattered first-fit (DESIGN.md §11.2).
     """
-    J = jobs.capacity
-    need = jobs.nodes[idx] - state.free
-    running = state.jstate == RUNNING
-    lower = running & (jobs.priority > jobs.priority[idx])
-    # order victims by (priority desc, row desc) via a two-stage
-    # lexicographic sort — the packed key ``-(priority*J + row)`` the seed
-    # engine used overflows int32 for priorities near INF_TIME (mirrors
-    # select_preempt's two-stage argmin; non-victims sort last)
-    rows = jnp.arange(J, dtype=jnp.int32)
-    big = jnp.int32(INF_TIME)
-    order = jnp.lexsort((jnp.where(lower, -rows, big),
-                         jnp.where(lower, -jobs.priority, big)))
-    nodes_o = jnp.where(lower, jobs.nodes, 0)[order]
-    cum = jnp.cumsum(nodes_o)
-    # preempt the minimal prefix whose cumulative nodes cover the deficit
-    take_rank = jnp.where(cum - nodes_o < jnp.maximum(need, 0), True, False)
-    take_rank = take_rank & (nodes_o > 0)
-    victim = jnp.zeros((J,), bool).at[order].set(take_rank)
-    freed = jnp.sum(jnp.where(victim, jobs.nodes, 0)).astype(jnp.int32)
-    new_remaining = jnp.where(
-        victim, jnp.maximum(state.finish - state.clock, 1), state.remaining
-    )
-    node_owner = (state.node_owner if ctx is None
-                  else _release_nodes(state.node_owner, victim, J))
-    return dataclasses.replace(
-        state,
-        jstate=jnp.where(victim, WAITING, state.jstate),
-        finish=jnp.where(victim, INF_TIME, state.finish),
-        rsv_finish=jnp.where(victim, INF_TIME, state.rsv_finish),
-        remaining=new_remaining,
-        free=state.free + freed,
-        node_owner=node_owner,
-    )
+    with jax.named_scope("place"):
+        J = jobs.capacity
+        need = jobs.nodes[idx] - state.free
+        running = state.jstate == RUNNING
+        lower = running & (jobs.priority > jobs.priority[idx])
+        # order victims by (priority desc, row desc) via a two-stage
+        # lexicographic sort — the packed key ``-(priority*J + row)`` the
+        # seed engine used overflows int32 for priorities near INF_TIME
+        # (mirrors select_preempt's two-stage argmin; non-victims sort
+        # last)
+        rows = jnp.arange(J, dtype=jnp.int32)
+        big = jnp.int32(INF_TIME)
+        order = jnp.lexsort((jnp.where(lower, -rows, big),
+                             jnp.where(lower, -jobs.priority, big)))
+        nodes_o = jnp.where(lower, jobs.nodes, 0)[order]
+        cum = jnp.cumsum(nodes_o)
+        # preempt the minimal prefix whose cumulative nodes cover the deficit
+        take_rank = jnp.where(cum - nodes_o < jnp.maximum(need, 0), True,
+                              False)
+        take_rank = take_rank & (nodes_o > 0)
+        victim = jnp.zeros((J,), bool).at[order].set(take_rank)
+        freed = jnp.sum(jnp.where(victim, jobs.nodes, 0)).astype(jnp.int32)
+        new_remaining = jnp.where(
+            victim, jnp.maximum(state.finish - state.clock, 1), state.remaining
+        )
+        node_owner = (state.node_owner if ctx is None
+                      else _release_nodes(state.node_owner, victim, J))
+        return dataclasses.replace(
+            state,
+            jstate=jnp.where(victim, WAITING, state.jstate),
+            finish=jnp.where(victim, INF_TIME, state.finish),
+            rsv_finish=jnp.where(victim, INF_TIME, state.rsv_finish),
+            remaining=new_remaining,
+            free=state.free + freed,
+            node_owner=node_owner,
+        )
 
 
 def _select(policy: jax.Array, jobs: JobSet, state: SimState,
@@ -1028,125 +1033,132 @@ def _event_step(policy: jax.Array, jobs: JobSet, state: SimState,
                 rel: Optional[tuple] = None,
                 svc: Optional[tuple] = None,
                 mctx: Optional[tuple] = None) -> SimState:
-    pending = state.jstate == PENDING
-    running = state.jstate == RUNNING
-    has_deps = jobs.dep_dst is not None
-    mal_ticks = mctx is not None and mctx[2].shape[0] > 0
+    with jax.named_scope("advance"):
+        pending = state.jstate == PENDING
+        running = state.jstate == RUNNING
+        has_deps = jobs.dep_dst is not None
+        mal_ticks = mctx is not None and mctx[2].shape[0] > 0
 
-    # A PENDING job generates an arrival event only once its dependencies
-    # are DONE; unreleased dependents are invisible to the clock (and to
-    # backfill's shadow math, which never sees them as WAITING).  The
-    # pre-completion release mask is the standing counter — no recompute.
-    arrivable = pending & (state.n_unmet == 0) if has_deps else pending
-    t_arr = jnp.min(jnp.where(arrivable, jobs.submit, INF_TIME))
-    t_fin = jnp.min(jnp.where(running, state.finish, INF_TIME))
-    clock = jnp.minimum(t_arr, t_fin)
-    if rel is not None:
-        K = rel[0].shape[0]
-        p = state.rel.ptr
-        t_rel = jnp.where(p < K, rel[0][jnp.minimum(p, K - 1)],
-                          jnp.int32(INF_TIME))
-        clock = jnp.minimum(clock, t_rel)
-    if svc is not None and svc[1].shape[0] > 0:
-        # T == 0 (no autoscaler) statically elides the tick clock source
-        T = svc[1].shape[0]
-        p = state.svc.ptr
-        t_svc = jnp.where(p < T, svc[1][jnp.minimum(p, T - 1)],
-                          jnp.int32(INF_TIME))
-        clock = jnp.minimum(clock, t_svc)
-    if mal_ticks:
-        # T == 0 (moldable mode) statically elides the resize clock source
-        Tm = mctx[2].shape[0]
-        p = state.mal.ptr
-        t_mal = jnp.where(p < Tm, mctx[2][jnp.minimum(p, Tm - 1)],
-                          jnp.int32(INF_TIME))
-        clock = jnp.minimum(clock, t_mal)
-
-    # completions first (frees nodes for arrivals at the same timestamp);
-    # with malleability a completing job frees its current width and closes
-    # its node-second segment
-    completed = running & (state.finish <= clock)
-    eff_nodes = jobs.nodes if mctx is None else state.mal.width
-    freed = jnp.sum(jnp.where(completed, eff_nodes, 0)).astype(jnp.int32)
-    jstate = jnp.where(completed, DONE, state.jstate)
-    node_owner = (state.node_owner if ctx is None
-                  else _release_nodes(state.node_owner, completed, jobs.capacity))
-    mal_after = state.mal
-    if mctx is not None:
-        closed = jnp.where(completed,
-                           state.mal.width * (clock - state.mal.seg_start), 0)
-        mal_after = dataclasses.replace(
-            state.mal, node_s=state.mal.node_s + closed)
-
-    # arrivals — dependents of this event's completions release *now*
-    # (paper §3 release rule): each RUNNING->DONE transition happens exactly
-    # once, so decrementing n_unmet along the completing jobs' out-edges
-    # keeps the counters exact; a job whose last dependency just finished
-    # joins the wait queue in the same event, with ready_time = max(submit,
-    # last dep finish).  Padding edges scatter out of range and drop.
-    n_unmet = state.n_unmet
-    if has_deps:
-        J = jobs.capacity
-        dec = completed[jnp.clip(jobs.dep_src, 0, J - 1)].astype(jnp.int32)
-        if csr is not None:
-            row_start, row_end = csr
-            c = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                 jnp.cumsum(dec)])
-            n_unmet = n_unmet - (c[row_end] - c[row_start])
-        else:
-            n_unmet = n_unmet.at[jobs.dep_dst].add(-dec, mode="drop")
-    if rel is not None or svc is not None or mal_ticks:
-        # stream events run after completions (a job finishing at the
-        # failure/tick instant has completed) and before arrivals (a job
-        # whose last dependency aborts still releases within this same
-        # event; autoscale and resize ticks read queued demand *before*
-        # this event's arrivals join the queue) — order: completions,
-        # reliability, capacity ticks, resize ticks, arrivals
-        state = dataclasses.replace(
-            state, clock=clock, jstate=jstate, n_unmet=n_unmet,
-            free=state.free + freed, node_owner=node_owner, mal=mal_after)
+        # A PENDING job generates an arrival event only once its dependencies
+        # are DONE; unreleased dependents are invisible to the clock (and to
+        # backfill's shadow math, which never sees them as WAITING).  The
+        # pre-completion release mask is the standing counter — no recompute.
+        arrivable = pending & (state.n_unmet == 0) if has_deps else pending
+        t_arr = jnp.min(jnp.where(arrivable, jobs.submit, INF_TIME))
+        t_fin = jnp.min(jnp.where(running, state.finish, INF_TIME))
+        clock = jnp.minimum(t_arr, t_fin)
         if rel is not None:
-            state = _process_rel_events(jobs, state, ctx, rel, mctx)
+            K = rel[0].shape[0]
+            p = state.rel.ptr
+            t_rel = jnp.where(p < K, rel[0][jnp.minimum(p, K - 1)],
+                              jnp.int32(INF_TIME))
+            clock = jnp.minimum(clock, t_rel)
         if svc is not None and svc[1].shape[0] > 0:
-            state = _process_capacity_ticks(jobs, state, ctx, svc)
+            # T == 0 (no autoscaler) statically elides the tick clock source
+            T = svc[1].shape[0]
+            p = state.svc.ptr
+            t_svc = jnp.where(p < T, svc[1][jnp.minimum(p, T - 1)],
+                              jnp.int32(INF_TIME))
+            clock = jnp.minimum(clock, t_svc)
         if mal_ticks:
-            state = _process_mal_ticks(jobs, state, ctx, mctx)
-        jstate, n_unmet = state.jstate, state.n_unmet
-        arrived = (jstate == PENDING) & (jobs.submit <= clock)
-        if has_deps:
-            arrived = arrived & (n_unmet == 0)
-        jstate = jnp.where(arrived, WAITING, jstate)
-        state = dataclasses.replace(
-            state, jstate=jstate, n_events=state.n_events + 1)
-    else:
-        arrived = (jstate == PENDING) & (jobs.submit <= clock)
-        if has_deps:
-            arrived = arrived & (n_unmet == 0)
-        jstate = jnp.where(arrived, WAITING, jstate)
+            # T == 0 (moldable mode) statically elides the resize clock source
+            Tm = mctx[2].shape[0]
+            p = state.mal.ptr
+            t_mal = jnp.where(p < Tm, mctx[2][jnp.minimum(p, Tm - 1)],
+                              jnp.int32(INF_TIME))
+            clock = jnp.minimum(clock, t_mal)
 
-        state = dataclasses.replace(
-            state,
-            clock=clock,
-            jstate=jstate,
-            n_unmet=n_unmet,
-            free=state.free + freed,
-            n_events=state.n_events + 1,
-            node_owner=node_owner,
-            mal=mal_after,
-        )
-    state = _schedule_pass(policy, jobs, state, ctx, static_policy,
-                           fast_order, mctx)
+        # completions first (frees nodes for arrivals at the same timestamp);
+        # with malleability a completing job frees its current width and closes
+        # its node-second segment
+        completed = running & (state.finish <= clock)
+        eff_nodes = jobs.nodes if mctx is None else state.mal.width
+        freed = jnp.sum(jnp.where(completed, eff_nodes, 0)).astype(jnp.int32)
+        jstate = jnp.where(completed, DONE, state.jstate)
+        node_owner = (state.node_owner if ctx is None
+                      else _release_nodes(state.node_owner, completed,
+                                          jobs.capacity))
+        mal_after = state.mal
+        if mctx is not None:
+            closed = jnp.where(
+                completed, state.mal.width * (clock - state.mal.seg_start), 0)
+            mal_after = dataclasses.replace(
+                state.mal, node_s=state.mal.node_s + closed)
+
+        # arrivals — dependents of this event's completions release *now*
+        # (paper §3 release rule): each RUNNING->DONE transition happens
+        # exactly once, so decrementing n_unmet along the completing jobs'
+        # out-edges keeps the counters exact; a job whose last dependency
+        # just finished joins the wait queue in the same event, with
+        # ready_time = max(submit, last dep finish).  Padding edges scatter
+        # out of range and drop.
+        n_unmet = state.n_unmet
+        if has_deps:
+            J = jobs.capacity
+            dec = completed[jnp.clip(jobs.dep_src, 0, J - 1)].astype(jnp.int32)
+            if csr is not None:
+                row_start, row_end = csr
+                c = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                     jnp.cumsum(dec)])
+                n_unmet = n_unmet - (c[row_end] - c[row_start])
+            else:
+                n_unmet = n_unmet.at[jobs.dep_dst].add(-dec, mode="drop")
+        if rel is not None or svc is not None or mal_ticks:
+            # stream events run after completions (a job finishing at the
+            # failure/tick instant has completed) and before arrivals (a job
+            # whose last dependency aborts still releases within this same
+            # event; autoscale and resize ticks read queued demand *before*
+            # this event's arrivals join the queue) — order: completions,
+            # reliability, capacity ticks, resize ticks, arrivals
+            state = dataclasses.replace(
+                state, clock=clock, jstate=jstate, n_unmet=n_unmet,
+                free=state.free + freed, node_owner=node_owner, mal=mal_after)
+            if rel is not None:
+                with jax.named_scope("failures"):
+                    state = _process_rel_events(jobs, state, ctx, rel, mctx)
+            if svc is not None and svc[1].shape[0] > 0:
+                state = _process_capacity_ticks(jobs, state, ctx, svc)
+            if mal_ticks:
+                state = _process_mal_ticks(jobs, state, ctx, mctx)
+            jstate, n_unmet = state.jstate, state.n_unmet
+            arrived = (jstate == PENDING) & (jobs.submit <= clock)
+            if has_deps:
+                arrived = arrived & (n_unmet == 0)
+            jstate = jnp.where(arrived, WAITING, jstate)
+            state = dataclasses.replace(
+                state, jstate=jstate, n_events=state.n_events + 1)
+        else:
+            arrived = (jstate == PENDING) & (jobs.submit <= clock)
+            if has_deps:
+                arrived = arrived & (n_unmet == 0)
+            jstate = jnp.where(arrived, WAITING, jstate)
+
+            state = dataclasses.replace(
+                state,
+                clock=clock,
+                jstate=jstate,
+                n_unmet=n_unmet,
+                free=state.free + freed,
+                n_events=state.n_events + 1,
+                node_owner=node_owner,
+                mal=mal_after,
+            )
+    with jax.named_scope("schedule"):
+        state = _schedule_pass(policy, jobs, state, ctx, static_policy,
+                               fast_order, mctx)
     if ctx is None:
         return state
     # fragmentation log: one (clock, free, largest-free-block) row per event
-    slot = state.n_events - 1
-    return dataclasses.replace(
-        state,
-        ev_time=state.ev_time.at[slot].set(state.clock, mode="drop"),
-        ev_free=state.ev_free.at[slot].set(state.free, mode="drop"),
-        ev_lfb=state.ev_lfb.at[slot].set(
-            _alloc.largest_free_run(_owner_eff(jobs, state)), mode="drop"),
-    )
+    with jax.named_scope("log"):
+        slot = state.n_events - 1
+        return dataclasses.replace(
+            state,
+            ev_time=state.ev_time.at[slot].set(state.clock, mode="drop"),
+            ev_free=state.ev_free.at[slot].set(state.free, mode="drop"),
+            ev_lfb=state.ev_lfb.at[slot].set(
+                _alloc.largest_free_run(_owner_eff(jobs, state)),
+                mode="drop"),
+        )
 
 
 def make_alloc_ctx(machine, strategy, contention,

@@ -8,6 +8,8 @@ parallel sweeps).  Routes:
 - ``GET  /health``  — liveness + queue names;
 - ``GET  /fleet``   — per-queue baseline metrics (fleet-status aggregation);
 - ``GET  /cache``   — sweep executable-cache counters;
+- ``GET  /stats``   — the process's counter table (:mod:`repro.obs`):
+  queries by kind, errors, sweep buckets and lanes, XLA compiles;
 - ``POST /query``   — one :class:`WhatIfQuery` JSON document in, one
   recommendation response out.
 
@@ -18,16 +20,20 @@ with no failure model), 405/404 for bad routes.
 
 ``python -m repro.service --fleet fleet.json`` serves a fleet config;
 ``--demo`` serves a small built-in three-queue fleet (what the CI smoke
-test and ``examples/whatif_queries.py`` use).
+test and ``examples/whatif_queries.py`` use); ``--profiler-port`` lets a
+profiler capture the running service (README, "Tracing a query, a
+run or a sweep").
 """
 
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional, Tuple
 
+from repro import obs
 from repro.api import FailureModel, Scenario, SyntheticTrace, Topology
 from repro.compile_cache import enable_compile_cache
 
@@ -40,6 +46,8 @@ from repro.service.query import (
 # client's fault (400); one that is well-formed but unanswerable here is 422
 _STATUS_BY_CODE = {"unknown_field": 400, "missing_field": 400,
                    "bad_value": 400, "bad_version": 400, "unsupported": 422}
+# the ``id`` of each POST /query's host spans: a per-process request number
+_REQUEST_IDS = itertools.count()
 
 
 def demo_fleet() -> Dict[str, Scenario]:
@@ -69,14 +77,16 @@ class _Handler(BaseHTTPRequestHandler):
     # -- plumbing ------------------------------------------------------------
 
     def _send(self, status: int, payload: Dict[str, Any]) -> None:
-        body = canonical_dumps(payload).encode("utf-8")
-        self.send_response(status)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        with obs.span("service.encode"):
+            body = canonical_dumps(payload).encode("utf-8")
+            self.send_response(status)
+            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
 
     def _error(self, status: int, err_type: str, message: str) -> None:
+        obs.count("service.errors")
         self._send(status, {"error": {"type": err_type, "message": message}})
 
     def log_message(self, fmt, *args):  # quiet by default
@@ -95,10 +105,12 @@ class _Handler(BaseHTTPRequestHandler):
                 self._send(200, planner.fleet_status())
             elif self.path == "/cache":
                 self._send(200, planner.fleet_status()["cache"])
+            elif self.path == "/stats":
+                self._send(200, obs.counters())
             else:
                 self._error(404, "not_found",
                             f"no route {self.path!r}; routes: /health "
-                            "/fleet /cache, POST /query")
+                            "/fleet /cache /stats, POST /query")
         except Exception as e:  # noqa: BLE001 — a request must not kill the server
             self._error(500, "internal", f"{type(e).__name__}: {e}")
 
@@ -107,17 +119,24 @@ class _Handler(BaseHTTPRequestHandler):
             self._error(404, "not_found",
                         f"no POST route {self.path!r}; POST /query")
             return
-        try:
-            length = int(self.headers.get("Content-Length", 0))
-            body = self.rfile.read(length).decode("utf-8")
-            query = WhatIfQuery.from_json(body)
-            self._send(200, self.server.planner.answer(query))
-        except SchemaError as e:
-            self._error(_STATUS_BY_CODE.get(e.code, 400), e.code, str(e))
-        except UnknownQueueError as e:
-            self._error(404, "unknown_queue", str(e))
-        except Exception as e:  # noqa: BLE001
-            self._error(500, "internal", f"{type(e).__name__}: {e}")
+        # host spans of one request share its id; they nest by time on
+        # this handler thread: request > decode, planner.*, encode
+        with obs.span("service.request", id=next(_REQUEST_IDS)) \
+                as request:
+            try:
+                with obs.span("service.decode"):
+                    length = int(self.headers.get("Content-Length", 0))
+                    body = self.rfile.read(length).decode("utf-8")
+                    query = WhatIfQuery.from_json(body)
+                request.set_metadata(kind=query.kind)
+                obs.count(f"service.queries.{query.kind}")
+                self._send(200, self.server.planner.answer(query))
+            except SchemaError as e:
+                self._error(_STATUS_BY_CODE.get(e.code, 400), e.code, str(e))
+            except UnknownQueueError as e:
+                self._error(404, "unknown_queue", str(e))
+            except Exception as e:  # noqa: BLE001
+                self._error(500, "internal", f"{type(e).__name__}: {e}")
 
 
 class WhatIfServer(ThreadingHTTPServer):
@@ -158,8 +177,16 @@ def main(argv: Optional[list] = None) -> int:
                         help="0 picks a free port (printed on startup)")
     parser.add_argument("--verbose", action="store_true",
                         help="log every request")
+    parser.add_argument("--profiler-port", type=int, default=None,
+                        help="start JAX's profiler server on this port, so "
+                        "that a capture (python -m jax.collect_profile "
+                        "PORT MS) records the service's spans")
     args = parser.parse_args(argv)
     enable_compile_cache()
+    if args.profiler_port is not None:
+        import jax
+
+        jax.profiler.start_server(args.profiler_port)
 
     if args.demo:
         fleet = demo_fleet()

@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.api import Scenario, cache_stats, sweep
 from repro.api.result import Result
 from repro.core import metrics
@@ -209,8 +210,8 @@ class CapacityPlanner:
                                   f"has {base.total_nodes}",
                 })
                 continue
-            delta = ScenarioDelta(inject=(job,))
-            scn = apply_delta(base, delta)
+            with obs.span("planner.lower", points=1):
+                scn = apply_delta(base, ScenarioDelta(inject=(job,)))
             res = _single_point(scn)
             points.append({
                 "label": name, "queue": name, "scenario": scn,
@@ -221,7 +222,8 @@ class CapacityPlanner:
 
     def _eval_capacity(self, query: WhatIfQuery) -> List[Dict[str, Any]]:
         name, base = self.queue(query.queue)
-        scenarios = [apply_delta(base, d) for d in query.deltas]
+        with obs.span("planner.lower", points=len(query.deltas)):
+            scenarios = [apply_delta(base, d) for d in query.deltas]
         # a pure add_nodes grid on a scalar-counter queue is traced sweep
         # data: ONE executable runs every delta (DESIGN.md §12.2)
         nodes_only = base.topology is None and all(
@@ -270,69 +272,74 @@ class CapacityPlanner:
         """The JSON-able response for one query (module docstring)."""
         before = cache_stats()
         points = self.evaluate(query)
-        objective = query.default_objective()
-        rows = []
-        out_points = []
-        for p in points:
-            entry: Dict[str, Any] = {"label": p["label"],
-                                     "queue": p["queue"]}
-            if p.get("delta") is not None:
-                entry["delta"] = p["delta"].to_json_dict()
-            for k in ("mtbf", "checkpoint_interval"):
-                if k in p:
-                    entry[k] = p[k]
-            if p.get("infeasible"):
-                entry["infeasible"] = p["infeasible"]
+        # host span: the summaries (each fetches its result), baseline,
+        # ranking
+        with obs.span("planner.rank"):
+            objective = query.default_objective()
+            rows = []
+            out_points = []
+            for p in points:
+                entry: Dict[str, Any] = {"label": p["label"],
+                                         "queue": p["queue"]}
+                if p.get("delta") is not None:
+                    entry["delta"] = p["delta"].to_json_dict()
+                for k in ("mtbf", "checkpoint_interval"):
+                    if k in p:
+                        entry[k] = p[k]
+                if p.get("infeasible"):
+                    entry["infeasible"] = p["infeasible"]
+                    out_points.append(entry)
+                    continue
+                summ = enriched_summary(p["result"])
+                if p.get("candidate") is not None:
+                    entry["candidate"] = p["candidate"]
+                    summ["candidate_wait"] = (
+                        float(p["candidate"]["wait"])
+                        if p["candidate"]["started"] else float("nan"))
+                entry["summary"] = summ
+                rows.append((p["label"], summ))
                 out_points.append(entry)
-                continue
-            summ = enriched_summary(p["result"])
-            if p.get("candidate") is not None:
-                entry["candidate"] = p["candidate"]
-                summ["candidate_wait"] = (
-                    float(p["candidate"]["wait"])
-                    if p["candidate"]["started"] else float("nan"))
-            entry["summary"] = summ
-            rows.append((p["label"], summ))
-            out_points.append(entry)
-        if not rows:
-            raise SchemaError(
-                "unsupported", "no feasible evaluation point (every "
-                "candidate queue was too small for the job)")
+            if not rows:
+                raise SchemaError(
+                    "unsupported", "no feasible evaluation point (every "
+                    "candidate queue was too small for the job)")
 
-        baseline = None
-        if query.kind in ("capacity", "reliability"):
-            baseline = self.baseline_summary(points[0]["queue"])
-        try:
-            recs = metrics.rank_candidates(
-                rows, objective.metric, goal=objective.goal,
-                baseline=baseline, target=objective.target)
-        except KeyError as e:
-            raise SchemaError("bad_value", str(e))
+            baseline = None
+            if query.kind in ("capacity", "reliability"):
+                baseline = self.baseline_summary(points[0]["queue"])
+            try:
+                recs = metrics.rank_candidates(
+                    rows, objective.metric, goal=objective.goal,
+                    baseline=baseline, target=objective.target)
+            except KeyError as e:
+                raise SchemaError("bad_value", str(e))
 
-        # with a target, "recommended" is the first candidate IN INPUT
-        # ORDER meeting it (input order encodes the asker's cost
-        # preference: cheapest deltas first); without one, the best-ranked
-        recommended = None
-        if objective.target is not None:
-            by_label = {r["label"]: r for r in recs}
-            for label, _ in rows:
-                if by_label[label].get("meets_target"):
-                    recommended = label
-                    break
-        elif recs:
-            recommended = recs[0]["label"]
+            # with a target, "recommended" is the first candidate IN INPUT
+            # ORDER meeting it (input order encodes the asker's cost
+            # preference: cheapest deltas first); without one, the
+            # best-ranked
+            recommended = None
+            if objective.target is not None:
+                by_label = {r["label"]: r for r in recs}
+                for label, _ in rows:
+                    if by_label[label].get("meets_target"):
+                        recommended = label
+                        break
+            elif recs:
+                recommended = recs[0]["label"]
 
         after = cache_stats()
-        return jsonable({
-            "version": SCHEMA_VERSION,
-            "kind": query.kind,
-            "queue": query.queue,
-            "objective": objective.to_json_dict(),
-            "baseline": baseline,
-            "points": out_points,
-            "recommendations": recs,
-            "recommended": recommended,
-            "cache": {"compiles": after.compiles - before.compiles,
-                      "hits": after.hits - before.hits,
-                      "entries": after.entries},
-        })
+        with obs.span("service.encode"):
+            return jsonable({
+                "version": SCHEMA_VERSION,
+                "kind": query.kind,
+                "queue": query.queue,
+                "objective": objective.to_json_dict(),
+                "baseline": baseline,
+                "points": out_points,
+                "recommendations": recs,
+                "recommended": recommended,
+                "cache": {"compiles": after.compiles - before.compiles,
+                          "hits": after.hits - before.hits,
+                          "entries": after.entries},
+            })
