@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -117,32 +118,35 @@ class Result:
 def simresult_to_np(res: SimResult, jobs: JobSet, *, with_alloc: bool,
                     service=None) -> Dict[str, np.ndarray]:
     """``SimResult`` + ``JobSet`` -> the canonical numpy dict (the schema
-    ``simulate_np`` established; shared by every backend)."""
+    ``simulate_np`` established; shared by every backend).  The result and
+    the job columns cross to the host in one ``jax.device_get``."""
+    res, (submit, nodes, runtime, valid) = jax.device_get(
+        (res, (jobs.submit, jobs.nodes, jobs.runtime, jobs.valid)))
     out = {
-        "submit": np.asarray(jobs.submit),
-        "nodes": np.asarray(jobs.nodes),
-        "runtime": np.asarray(jobs.runtime),
-        "start": np.asarray(res.start),
-        "finish": np.asarray(res.finish),
-        "ready": np.asarray(res.ready),
-        "wait": np.asarray(res.wait),
+        "submit": submit,
+        "nodes": nodes,
+        "runtime": runtime,
+        "start": res.start,
+        "finish": res.finish,
+        "ready": res.ready,
+        "wait": res.wait,
         "makespan": int(res.makespan),
         "n_events": int(res.n_events),
-        "done": np.asarray(res.done),
-        "valid": np.asarray(jobs.valid),
+        "done": res.done,
+        "valid": valid,
     }
     if with_alloc:
         n_ev = out["n_events"]
-        out["alloc_first"] = np.asarray(res.alloc_first)
-        out["alloc_span"] = np.asarray(res.alloc_span)
-        out["alloc_sum"] = np.asarray(res.alloc_sum)
-        out["ev_time"] = np.asarray(res.ev_time)[:n_ev]
-        out["ev_free"] = np.asarray(res.ev_free)[:n_ev]
-        out["ev_lfb"] = np.asarray(res.ev_lfb)[:n_ev]
+        out["alloc_first"] = res.alloc_first
+        out["alloc_span"] = res.alloc_span
+        out["alloc_sum"] = res.alloc_sum
+        out["ev_time"] = res.ev_time[:n_ev]
+        out["ev_free"] = res.ev_free[:n_ev]
+        out["ev_lfb"] = res.ev_lfb[:n_ev]
     if res.rel is not None:
-        out["n_restarts"] = np.asarray(res.rel.n_restarts)
-        out["lost_work"] = np.asarray(res.rel.lost_work)
-        out["aborted"] = np.asarray(res.rel.aborted)
+        out["n_restarts"] = res.rel.n_restarts
+        out["lost_work"] = res.rel.lost_work
+        out["aborted"] = res.rel.aborted
     if res.mal is not None:
         # chosen/final width, reference width, resize count, node-second
         # ledger and dispatch-time dilated duration (DESIGN.md §17); rows
@@ -153,12 +157,12 @@ def simresult_to_np(res: SimResult, jobs: JobSet, *, with_alloc: bool,
         out["mal_node_s"] = np.asarray(res.mal.node_s, dtype=np.int64)
         out["mal_dur"] = np.asarray(res.mal.disp_dur, dtype=np.int64)
     if res.svc is not None:
-        out["slo_met"] = np.asarray(res.svc.slo_met)
-        out["deadline"] = np.asarray(res.svc.deadline)
+        out["slo_met"] = res.svc.slo_met
+        out["deadline"] = res.svc.deadline
         # capacity series: the engine logs the online level per consumed
         # tick (-1 = never consumed); the times come from the plan's tick
         # stream, which is how the refsim emits the same two columns
-        cap = np.asarray(res.svc.cap_online)
+        cap = res.svc.cap_online
         used = cap >= 0
         out["cap_online"] = cap[used].astype(np.int64)
         if service is not None:

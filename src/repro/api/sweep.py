@@ -24,7 +24,8 @@ compiled executables as possible:
    by the lazy full-sort guard in ``policies.backfill_shadow``
    (DESIGN.md §18);
 4. the batched outputs are re-sliced into per-point :class:`Result`\\ s in
-   grid order.
+   grid order: one unstack program per output leaf (per 100 lanes), each
+   lane's device-to-host copy started at once.
 
 This replaces ``simulate_alloc_sweep`` (an alloc-only special case,
 regression-tested bit-exact in ``tests/test_api.py``) and every
@@ -42,6 +43,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import alloc as _alloc
@@ -406,7 +408,8 @@ def _run_bucket(bucket: List[Scenario], mesh: Optional[Mesh]) -> List[Result]:
 
     Host spans: ``sweep.prepare`` (job tables, contexts, stacking),
     ``sweep.dispatch`` (signature log and the jitted call; a cold
-    signature compiles here) and ``sweep.split`` (per-lane results)."""
+    signature compiles here) and ``sweep.split`` (per-lane results,
+    :func:`_split_lanes`)."""
     lanes = len(bucket)
     obs.count("sweep.buckets")
     obs.count("sweep.lanes", lanes)
@@ -423,9 +426,45 @@ def _run_bucket(bucket: List[Scenario], mesh: Optional[Mesh]) -> List[Result]:
         batched = fn(*args) if machine is None else fn(*args, machine)
 
     with obs.span("sweep.split", lanes=lanes):
-        return [
-            Result(scenario=scn, backend="jax",
-                   raw=jax.tree.map(lambda a, i=i: a[i], batched),
-                   jobs=jobsets[i])
-            for i, scn in enumerate(bucket)
-        ]
+        raws = _split_lanes(batched, lanes)
+        return [Result(scenario=scn, backend="jax", raw=raws[i],
+                       jobs=jobsets[i])
+                for i, scn in enumerate(bucket)]
+
+
+_UNSTACK_ROWS = 100     # outputs of one unstack program at most
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _unstack(x: jax.Array, start: int, rows: int) -> List[jax.Array]:
+    # ``rows`` rows of ``x`` from ``start``: one program per leaf shape,
+    # whatever the bucket's size, so the bucket's warm-up compiles it
+    block = lax.dynamic_slice_in_dim(x, start, rows)
+    return [lax.index_in_dim(block, i, keepdims=False) for i in range(rows)]
+
+
+def _rows(x: jax.Array) -> List[jax.Array]:
+    """Every row of ``x``, at most ``_UNSTACK_ROWS`` a program; the last
+    block ends at the last row, so every block has one shape."""
+    n = x.shape[0]
+    rows = min(n, _UNSTACK_ROWS)
+    out: List[jax.Array] = []
+    for lo in range(0, n, rows):
+        start = min(lo, n - rows)
+        out += _unstack(x, start, rows)[lo - start:]
+    return out
+
+
+def _split_lanes(batched: Any, lanes: int) -> List[Any]:
+    """The first ``lanes`` lanes of a batched result, one pytree each, with
+    every leaf's device-to-host copy already started.  Each leaf is cut by
+    unstack programs (counter ``sweep.split.unstacked``, in leaves), on one
+    device or sharded over a mesh alike."""
+    leaves, treedef = jax.tree.flatten(batched)
+    obs.count("sweep.split.unstacked", len(leaves))
+    cols = [_rows(a)[:lanes] for a in leaves]
+    for col in cols:
+        for a in col:
+            a.copy_to_host_async()
+    return [treedef.unflatten([col[i] for col in cols])
+            for i in range(lanes)]

@@ -1,6 +1,8 @@
 """Multi-cluster DES under a real device mesh (subprocess, 4 host devices):
 the shard_map + all_gather migration path must match the single-device
-vmapped path bit-for-bit (conservative-sync correctness on actual SPMD)."""
+vmapped path bit-for-bit (conservative-sync correctness on actual SPMD).
+A ``sweep(mesh=)`` bucket, sharded over the same mesh, gives the
+single-device answers."""
 
 import os
 import subprocess
@@ -49,3 +51,40 @@ def test_multicluster_sharded_matches_single_device(tmp_path):
     p = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                        capture_output=True, text=True, timeout=540)
     assert "SHARDED_OK" in p.stdout, (p.stdout[-400:], p.stderr[-800:])
+
+
+_SWEEP_CHILD = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    sys.path.insert(0, "src")
+    import jax, numpy as np
+    from jax.sharding import Mesh
+    from repro import obs
+    from repro.api import Scenario, SyntheticTrace, sweep
+
+    scn = Scenario(trace=SyntheticTrace(n_jobs=30, seed=1, kind="sdsc_sp2"),
+                   total_nodes=32, policy="backfill")
+    axes = {"policy": ("fcfs", "sjf", "backfill")}   # 3 lanes, 1 padded
+    mesh = Mesh(np.array(jax.devices()), ("sim",))
+    key = "sweep.split.unstacked"
+    c0 = obs.counters().get(key, 0)
+    a = sweep(scn, axes, mesh=mesh)
+    c1 = obs.counters().get(key, 0)
+    b = sweep(scn, axes)
+    leaves = len(jax.tree.leaves(b.results[0].raw))
+    assert c1 - c0 == leaves, (c1 - c0, leaves)
+    for x, y in zip(a.results, b.results):
+        p, q = x.to_np(), y.to_np()
+        assert set(p) == set(q)
+        for k in p:
+            assert np.array_equal(p[k], q[k]), k
+    print("SWEEP_SHARDED_OK lanes=", len(a.results))
+""")
+
+
+@pytest.mark.timeout(600)
+def test_sharded_sweep_matches_one_device(tmp_path):
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
+    p = subprocess.run([sys.executable, "-c", _SWEEP_CHILD], env=env,
+                       capture_output=True, text=True, timeout=540)
+    assert "SWEEP_SHARDED_OK" in p.stdout, (p.stdout[-400:], p.stderr[-800:])

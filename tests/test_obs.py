@@ -13,6 +13,7 @@ import contextlib
 import json
 import re
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -199,10 +200,23 @@ CAPACITY = {"version": 1, "kind": "capacity", "queue": "q",
             "deltas": [{"add_nodes": 0}, {"add_nodes": 16}]}
 
 
+def handlers_done(timeout=60):
+    """Wait until the server's request handler threads have ended: a
+    handler leaves its spans only after the client has read the answer,
+    and a span still open when the profiler stops is not recorded."""
+    deadline = time.monotonic() + timeout
+    while (any("process_request_thread" in t.name
+               for t in threading.enumerate())
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+
+
 def test_service_request_spans_nest_and_share_an_id(server, tmp_path):
     post(server.url + "/query", CAPACITY)     # warm
+    handlers_done()
     with jax.profiler.trace(str(tmp_path)):
         status, _ = post(server.url + "/query", CAPACITY)
+        handlers_done()
     assert status == 200
     spans = program_spans(tmp_path)
     req, = [s for s in spans if s[0] == "repro.service.request"]
