@@ -12,16 +12,22 @@ the whole trace.
 
 from __future__ import annotations
 
+import copy
 import os
 import shutil
 
 import numpy as np
 
 from lib import stats, workload
-from lib.entry import Entry, job_mismatches
+from lib.entry import Entry, job_mismatches, small_machine
 
 
 class ReplayEntry(Entry):
+    @classmethod
+    def small(cls, config, traffic):
+        return small_machine(config), dict(copy.deepcopy(traffic),
+                                           jobs=1200, window=256)
+
     def _ckpt_dir(self) -> str:
         return os.path.join(self.workdir, "replay_ckpt")
 

@@ -3,38 +3,43 @@
 Each question is a backlog of ``jobs`` jobs of the configuration's
 workload (one of the traffic's ``backlogs``, in turn), run on every point of
 the traffic's ``axes`` (policy × alloc × MTBF) in one vmapped executable,
-with that backlog's failure stream.  It ends when every lane's summary is
-on the host.  The check runs the plain
+with that backlog's failure stream; on more than one chip the bucket is
+sharded over a 1-D mesh of them, its lanes split evenly.  It ends when
+every lane's summary is on the host.  The check runs the plain
 reference on a seeded sample of lanes (every policy × alloc pair, each at a
 random MTBF of a random question), each against its own scenario.
 """
 
 from __future__ import annotations
 
+import copy
+import functools
 import itertools
 
 import numpy as np
 
 from lib import stats, workload
-from lib.entry import Entry, job_mismatches, keep_columns
+from lib.entry import Entry, job_mismatches, keep_columns, small_machine
 
 
 class SweepEntry(Entry):
-    def _base(self, trace: dict, fail_seed: int):
-        api = self.program.api
-        cfg = self.config
-        topo = cfg["machine"]["topology"]
-        f = cfg["failures"]
-        return api.Scenario(
-            trace=api.ArrayTrace.from_dict(trace),
-            topology=api.Topology(topo["kind"], tuple(topo["shape"])),
-            policy=cfg["policy"], alloc=cfg["alloc"],
-            failures=api.FailureModel(
-                mtbf=f["mtbf"], seed=fail_seed, horizon=f["horizon"],
-                max_failures=f["max_failures"], mean_repair=f["mean_repair"],
-                requeue=f["requeue"],
-                checkpoint_interval=f["checkpoint_interval"],
-                restart_overhead=f["restart_overhead"]))
+    SHARDS = True
+
+    @classmethod
+    def small(cls, config, traffic):
+        trf = copy.deepcopy(traffic)
+        trf.update(jobs=48, backlogs=2)
+        trf["axes"]["failures.mtbf"] = [2e5, 4e5]
+        return small_machine(config), trf
+
+    @functools.cached_property
+    def mesh(self):
+        """A 1-D mesh over the cell's chips, or None on one chip."""
+        if len(self.devices) < 2:
+            return None
+        from jax.sharding import Mesh
+
+        return Mesh(np.array(self.devices), ("lanes",))
 
     def _axes(self) -> dict:
         return {k: tuple(v) for k, v in self.traffic["axes"].items()}
@@ -45,7 +50,7 @@ class SweepEntry(Entry):
                  "runtime": np.ones(n, np.int64),
                  "nodes": np.ones(n, np.int64),
                  "estimate": np.ones(n, np.int64)}
-        self.call((self._base(drain, 1), None))
+        self.call((self.scenario(drain, 1), None))
 
     def question(self, q: int):
         """Host inputs of question ``q``: its backlog and failure seed.
@@ -56,19 +61,17 @@ class SweepEntry(Entry):
         a tenth with its failure stream alone, and a window holds about
         ``backlogs`` buckets: so every run answers the same questions, and
         the seed moves their order and the lanes checked."""
-        backlog = (self.seed + q) % self.traffic["backlogs"]
-        backlog_seed = workload.question_seed(
-            self.config["workload"]["sample_seed"], backlog)
+        backlog_seed = self.backlog_seed(q)
         trace = workload.config_trace(self.config, self.traffic["jobs"],
                                       backlog_seed)
         return trace, workload.question_seed(backlog_seed, 0)
 
     def prepare(self, q: int):
         trace, fail_seed = self.question(q)
-        return self._base(trace, fail_seed), (trace, fail_seed)
+        return self.scenario(trace, fail_seed), (trace, fail_seed)
 
     def call(self, inp):
-        grid = self.program.api.sweep(inp[0], self._axes())
+        grid = self.program.api.sweep(inp[0], self._axes(), mesh=self.mesh)
         return grid, grid.summaries()
 
     def collect(self, q: int, inp, out) -> dict:

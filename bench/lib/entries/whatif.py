@@ -14,6 +14,7 @@ compares the answer's summary (and candidate) field by field.
 
 from __future__ import annotations
 
+import copy
 import functools
 import json
 import os
@@ -24,7 +25,7 @@ import urllib.request
 import numpy as np
 
 from lib import stats, workload
-from lib.entry import Entry
+from lib.entry import Entry, small_machine
 
 SUMMARY_FIELDS = ("n_jobs", "avg_wait", "p50_wait", "p95_wait", "p99_wait",
                   "max_wait", "avg_bounded_slowdown", "makespan",
@@ -44,6 +45,11 @@ def _post(url: str, doc: dict) -> dict:
 
 
 class WhatIfEntry(Entry):
+    @classmethod
+    def small(cls, config, traffic):
+        return small_machine(config), dict(copy.deepcopy(traffic),
+                                           queue_jobs=200)
+
     def warm_up(self) -> None:
         cfg = self.config
         if cfg["machine"].get("topology") is not None or cfg.get("failures"):

@@ -6,12 +6,15 @@ then for each question of the window :meth:`prepare`, :meth:`call` and
 :meth:`collect`, then :meth:`end_to_end` and :meth:`counters`, and, once the
 window is closed and the program's state is dropped, :meth:`check`.  A
 traffic file names its entry under ``"entry"``; the harness loads
-``lib/entries/<entry>.py`` and takes its ``ENTRY`` class.
+``lib/entries/<entry>.py`` and takes its ``ENTRY`` class.  Each ``ENTRY``
+also has a classmethod ``small(config, traffic)``: copies of both, cut to a
+size the benchmark's CPU tests hold.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+import copy
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -25,13 +28,18 @@ FAILURE_COLUMNS = ("n_restarts", "lost_work")
 class Entry:
     """One entry point under test (module docstring)."""
 
+    # whether the entry spreads its work over more than one of the cell's
+    # chips; the harness refuses a cell of several chips for one that does not
+    SHARDS = False
+
     def __init__(self, program, config: dict, traffic: dict, seed: int,
-                 workdir: str):
+                 workdir: str, devices: Optional[Sequence] = None):
         self.program = program      # namespace of the program's modules
         self.config = config
         self.traffic = traffic
         self.seed = int(seed)
         self.workdir = workdir
+        self.devices = list(devices or [])  # the cell's chips
 
     # -- the window ---------------------------------------------------------
 
@@ -74,6 +82,45 @@ class Entry:
         rest = rng.permutation(n_items - 1)[:k - 1].tolist()
         return sorted(set(rest) | {n_items - 1})
 
+    def backlog_seed(self, q: int) -> int:
+        """The seed of question ``q``'s backlog.  With ``backlogs`` in the
+        traffic, one of that many fixed backlogs, in turn from a point the
+        seed picks, so every run does the same work and the seed moves its
+        order; without, a fresh backlog for each question."""
+        n = self.traffic.get("backlogs")
+        if n:
+            return workload.question_seed(
+                self.config["workload"]["sample_seed"], (self.seed + q) % n)
+        return workload.question_seed(self.seed, q)
+
+    def scenario(self, trace: dict, fail_seed: Optional[int] = None):
+        """The program's scenario of ``trace`` under this configuration: a
+        scalar queue, or a machine of the configuration's topology and
+        allocator with its failure model drawn from ``fail_seed``."""
+        api = self.program.api
+        cfg = self.config
+        topo = cfg["machine"].get("topology")
+        if topo is None:
+            if cfg.get("failures"):
+                raise ValueError("failures without a topology: add a "
+                                 "scalar failure path before using it")
+            return api.Scenario(trace=api.ArrayTrace.from_dict(trace),
+                                total_nodes=cfg["machine"]["nodes"],
+                                policy=cfg["policy"])
+        failures = None
+        f = cfg.get("failures")
+        if f:
+            failures = api.FailureModel(
+                mtbf=f["mtbf"], seed=fail_seed, horizon=f["horizon"],
+                max_failures=f["max_failures"], mean_repair=f["mean_repair"],
+                requeue=f["requeue"],
+                checkpoint_interval=f["checkpoint_interval"],
+                restart_overhead=f["restart_overhead"])
+        return api.Scenario(
+            trace=api.ArrayTrace.from_dict(trace),
+            topology=api.Topology(topo["kind"], tuple(topo["shape"])),
+            policy=cfg["policy"], alloc=cfg["alloc"], failures=failures)
+
     def machine_groups(self):
         topo = self.config["machine"].get("topology")
         if topo is None:
@@ -109,6 +156,20 @@ class Entry:
         if self.config.get("failures"):
             cols += FAILURE_COLUMNS
         return cols
+
+
+def small_machine(config: dict) -> dict:
+    """A copy of ``config`` whose machine a CPU test holds: a machine
+    becomes a 64-node dragonfly of 4 groups of 16, with failures 2e5 s
+    apart per node over 2^20 s; a scalar queue stays as it is."""
+    cfg = copy.deepcopy(config)
+    if cfg["machine"].get("topology"):
+        cfg["machine"] = {"nodes": 64,
+                          "topology": {"kind": "dragonfly", "shape": [4, 16]}}
+        cfg["workload"].update(total_nodes=64, node_pow2_max=6)
+        if cfg.get("failures"):
+            cfg["failures"].update(mtbf=2e5, horizon=1 << 20)
+    return cfg
 
 
 def job_mismatches(prog: dict, ref: dict, columns) -> int:

@@ -7,16 +7,17 @@ The harness is driven by data.  The cell names a configuration, found as
 ``bench/traffic/<traffic>.json``; the mix names the entry point it drives,
 found as ``bench/lib/entries/<entry>.py``; each per-layer metric is read by
 ``bench/metrics/<metric>.py``, or by ``bench/metrics/<stem>.py`` for a
-``<stem>.<variant>`` without a file of its own.  A new cell, mix or metric is new files and
-new entries, never an edit here.
+``<stem>.<variant>`` without a file of its own.  A new cell, mix or metric
+is new files and new entries, never an edit here.
 
 A run: refuse without a TPU (or with fewer chips than the cell asks for);
-switch the persistent compile cache on; warm up the cell's shapes (all of
-this is ``setup_s``); ask back-to-back questions for ``--seconds``, the last
-one finishing past the mark (with ``--trace 1`` the first of them, for about
-``TRACE_SECONDS``, under the profiler); read
-the peak device memory; drop the program's state; compare a seeded sample
-of the answers with the plain reference; print the result.
+hand the entry the cell's chips; switch the persistent compile cache on;
+warm up the cell's shapes (all of this is ``setup_s``); ask back-to-back
+questions for ``--seconds``, the last one finishing past the mark (with
+``--trace 1`` the first of them, for about ``TRACE_SECONDS``, under the
+profiler); read the peak device memory of the fullest chip; drop the
+program's state; compare a seeded sample of the answers with the plain
+reference; print the result.
 """
 
 from __future__ import annotations
@@ -164,14 +165,19 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
 
     config = config or load_json("configs", cell["config"])
     traffic = traffic or load_json("traffic", cell["traffic"])
+    cls = entry_class(traffic["entry"])
+    if cell["chips"] > 1 and not cls.SHARDS:
+        raise BenchError(f"cell {cell['name']} asks for {cell['chips']} "
+                         f"chips, but the {traffic['entry']} entry runs on "
+                         f"one")
     program = import_program()
     cache_dir = program.enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     meter = meter_mod.CompileMeter()
     os.makedirs(workdir, exist_ok=True)
 
-    entry = entry_class(traffic["entry"])(program, config, traffic, seed,
-                                          workdir)
+    devices = jax.devices()[:cell["chips"]]
+    entry = cls(program, config, traffic, seed, workdir, devices=devices)
     records: List[dict] = []
     failed = attempted = 0
     raw_trace = reduced = None
@@ -217,7 +223,7 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
             trace_mod.stop()
             n_traced = len(records)
         in_window = meter_mod.CompileMeter.delta(before, meter.snapshot())
-        mem = _peak_bytes()
+        mem = _peak_bytes(devices)
         if trace:
             path = trace_mod.find_xplane(trace_dir)
             if path is None:
@@ -270,12 +276,12 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
     return result
 
 
-def _peak_bytes() -> Optional[int]:
-    import jax
-
-    stats = jax.devices()[0].memory_stats() or {}
-    peak = stats.get("peak_bytes_in_use")
-    return int(peak) if peak is not None else None
+def _peak_bytes(devices) -> Optional[int]:
+    """The peak bytes in use on the fullest of ``devices``."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices]
+    peaks = [int(p) for p in peaks if p is not None]
+    return max(peaks) if peaks else None
 
 
 # ---------------------------------------------------------------------------

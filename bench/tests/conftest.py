@@ -3,7 +3,6 @@
     python -m pytest bench/tests
 """
 
-import copy
 import os
 import sys
 
@@ -16,38 +15,33 @@ import pytest  # noqa: E402
 
 from lib import harness  # noqa: E402
 
-CELLS = ("dragonfly_1024.sweep48", "sdsc_sp2_128.run8k",
-         "sdsc_sp2_128.whatif")
-# A cell whose entry, mix and readers stay under bench/ while it is out of
-# BENCHMARK.json (the replay compiles inside its own call): tested here, so
-# that it comes back as entries in BENCHMARK.json alone.
+# every cell of BENCHMARK.json: a cell is data, and its tests follow it
+CELLS = tuple(w["name"] for w in harness.load_benchmark()["workloads"])
+# Cells whose entry, mix and readers stay under bench/ while they are out of
+# BENCHMARK.json: tested here, so that each comes back as entries in
+# BENCHMARK.json alone.  The replay compiles inside its own call; the
+# machine-mode run's rate swings with a 1-2 s wait for the device that about
+# one run in ten holds.
 KEPT = {"sdsc_sp2_128.replay73k": {
     "name": "sdsc_sp2_128.replay73k", "config": "sdsc_sp2_128",
-    "traffic": "replay73k", "chips": 1}}
+    "traffic": "replay73k", "chips": 1},
+    "dragonfly_1024.run4k": {
+    "name": "dragonfly_1024.run4k", "config": "dragonfly_1024",
+    "traffic": "run4k", "chips": 1}}
 KEPT_METRICS = ("engine_us_per_event.replay", "replay_rounds",
                 "device_idle_share.replay")
 
 
-def small(cell_name: str):
-    """``(cell, config, traffic)`` of a cell, cut to a size a test holds."""
-    bench = harness.load_benchmark()
+def small(cell_name: str, bench=None):
+    """``(bench, cell, config, traffic)`` of a cell of ``bench`` (or of
+    ``KEPT``), config and traffic cut by the cell's entry to a size a test
+    holds."""
+    bench = bench or harness.load_benchmark()
     cell = KEPT.get(cell_name) or harness.find(bench["workloads"], cell_name,
                                                "workload")
-    cfg = copy.deepcopy(harness.load_json("configs", cell["config"]))
-    trf = copy.deepcopy(harness.load_json("traffic", cell["traffic"]))
-    if cfg["machine"].get("topology"):
-        cfg["machine"] = {"nodes": 64,
-                          "topology": {"kind": "dragonfly", "shape": [4, 16]}}
-        cfg["workload"].update(total_nodes=64, node_pow2_max=6)
-        cfg["failures"].update(mtbf=2e5, horizon=1 << 20)
-        trf.update(jobs=48, backlogs=2)
-        trf["axes"]["failures.mtbf"] = [2e5, 4e5]
-    elif trf["entry"] == "run":
-        trf["jobs"] = 300
-    elif trf["entry"] == "replay":
-        trf.update(jobs=1200, window=256)
-    elif trf["entry"] == "whatif":
-        trf["queue_jobs"] = 200
+    cfg = harness.load_json("configs", cell["config"])
+    trf = harness.load_json("traffic", cell["traffic"])
+    cfg, trf = harness.entry_class(trf["entry"]).small(cfg, trf)
     return bench, cell, cfg, trf
 
 

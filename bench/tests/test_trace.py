@@ -78,3 +78,34 @@ def test_recorded_chip_trace():
     assert r["module_s"][engine] == pytest.approx(0.803342775 + 0.787621186)
     assert r["device_ops"][0][0] == engine
     assert r["idle_gaps"][0] == ["question.call", pytest.approx(0.009255154)]
+
+
+def test_allreduce_time_per_lockstep_iteration():
+    """Every ``all-reduce*`` operation of the window, both halves of an
+    async pair, averaged over the chips and divided by the iterations."""
+    import types
+
+    from lib import harness
+
+    def chip(shift):
+        return {"modules": [["jit_fn(3)", 1 * NS, 8 * NS]],
+                "ops": [["%fusion.1", 1 * NS, 4 * NS],
+                        ["%all-reduce.5 = pred[] all-reduce(...)",
+                         5 * NS, (1 + shift) * NS],
+                        ["%all-reduce-start.2", 7 * NS, 1 * NS],
+                        ["%all-reduce-done.2", 8 * NS, 1 * NS],
+                        ["%all-reduce.9", 30 * NS, 5 * NS]]}   # outside
+
+    tr = {"devices": {"/device:TPU:0": chip(0), "/device:TPU:1": chip(2)},
+          "spans": [["question.call", 0, 20 * NS]]}
+    read = harness.metric_reader("allreduce_ms_per_iter")
+    view = types.SimpleNamespace(raw_trace=tr,
+                                 traced={"lockstep_iters": 2.0})
+    # (3 + 5) ms over two chips, over two iterations
+    assert read(view) == pytest.approx(2.0)
+    with open(FIXTURE) as f:
+        one_chip = json.load(f)
+    view.raw_trace = one_chip
+    assert read(view) is None
+    view.raw_trace = None
+    assert read(view) is None

@@ -108,3 +108,24 @@ def test_sweep_questions_repeat_their_backlogs_with_their_failures():
         np.testing.assert_array_equal(ta["submit"], tb["submit"])
     seeds = {a.question(q)[1] for q in range(k)}
     assert len(seeds) == k
+
+
+def test_run_questions_cycle_through_backlogs_or_are_fresh():
+    """With ``backlogs`` a run's questions take the fixed backlogs and their
+    failure streams in turn, as a sweep's do; without, every question is a
+    fresh backlog."""
+    from lib.entries.run import RunEntry
+
+    cfg = _config("dragonfly_1024")
+    trf = harness.load_json("traffic", "run4k")
+    k = trf["backlogs"]
+    a = RunEntry(None, cfg, trf, 5, "")
+    b = RunEntry(None, cfg, trf, 2**40 + 6, "")
+    for q in range(k):
+        (ta, fa), (tb, fb) = a.question(q + 1), b.question(q)
+        assert fa == fb
+        np.testing.assert_array_equal(ta["submit"], tb["submit"])
+    assert len({a.question(q)[1] for q in range(k)}) == k
+    fresh = {key: v for key, v in trf.items() if key != "backlogs"}
+    c = RunEntry(None, cfg, fresh, 5, "")
+    assert len({c.question(q)[1] for q in range(2 * k)}) == 2 * k
