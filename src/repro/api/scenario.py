@@ -33,6 +33,7 @@ from typing import Any, Dict, Optional, Tuple, Union
 import numpy as np
 
 from repro import alloc as _alloc
+from repro import obs
 from repro.core.jobs import INF_TIME
 from repro.malleable import MalleableModel
 from repro.reliability import FailureModel
@@ -139,13 +140,18 @@ class WorkflowTrace:
 
     ``kind`` selects the ``repro.traces.workflows`` generator: ``"montage"``,
     ``"galactic"`` (Galactic Plane: K montage tiles + merge), ``"sipht"``,
-    ``"chain"``, ``"fork_join"`` or ``"random"`` (random layered DAG).
+    ``"chain"``, ``"fork_join"``, ``"random"`` (random layered DAG) or
+    ``"montage_campaign"`` (mosaics of the published Montage profile at a
+    fixed submit gap; its shape, and so J and E, never depend on ``seed``).
     ``params`` are generator keyword arguments as (name, value) pairs —
     e.g. ``(("width", 16),)`` or ``(("tiles", 4), ("width", 8))``.  The DAG
     lowers through ``workflow_to_trace``: tasks become jobs (cpu requirement
     -> node count), edges become the ``JobSet.dep_dst``/``dep_src`` edge
-    list (O(E) per vmap leaf, DESIGN.md §14), and every task shares one
-    ``submit`` time so release order is purely dependency-driven.
+    list (O(E) per vmap leaf, DESIGN.md §14), and every task of one
+    workflow shares one ``submit`` time (``submit``, plus the mosaic's
+    offset in a campaign) so release order is purely dependency-driven.
+    Generation runs in the span ``repro.workflow.materialize`` and counts
+    ``workflow.tasks``; the last few DAGs are cached.
 
     The DAG *shape* (kind/params/submit/priority) is a static recompile
     axis; ``seed`` only perturbs task durations and random edges, so it is
@@ -168,6 +174,7 @@ class WorkflowTrace:
         "chain": _workflows.chain,
         "fork_join": _workflows.fork_join,
         "random": _workflows.random_layered,
+        "montage_campaign": _workflows.montage_campaign,
     }
     _SEEDLESS = frozenset({"chain"})
 
@@ -188,7 +195,8 @@ class WorkflowTrace:
         return len(self.materialize()["submit"])
 
 
-@functools.lru_cache(maxsize=128)
+# a handful of DAGs: a campaign holds 10^5 tasks and 2.5 * 10^5 edges
+@functools.lru_cache(maxsize=8)
 def _materialize_workflow(spec: WorkflowTrace) -> Dict[str, np.ndarray]:
     try:
         gen = spec._GENERATORS[spec.kind]
@@ -199,8 +207,12 @@ def _materialize_workflow(spec: WorkflowTrace) -> Dict[str, np.ndarray]:
     kwargs = dict(spec.params)
     if spec.kind not in spec._SEEDLESS:
         kwargs["seed"] = spec.seed
-    return workflow_to_trace(gen(**kwargs), submit=spec.submit,
-                             priority=spec.priority)
+    with obs.span("workflow.materialize") as span:
+        trace = workflow_to_trace(gen(**kwargs), submit=spec.submit,
+                                  priority=spec.priority)
+        span.set_metadata(tasks=len(trace["submit"]))
+    obs.count("workflow.tasks", len(trace["submit"]))
+    return trace
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

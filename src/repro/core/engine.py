@@ -1092,17 +1092,21 @@ def _event_step(policy: jax.Array, jobs: JobSet, state: SimState,
         # just finished joins the wait queue in the same event, with
         # ready_time = max(submit, last dep finish).  Padding edges scatter
         # out of range and drop.
+        # (named scope "release": this update and the arrival mask's
+        # dependency test, present only when the table carries edges)
         n_unmet = state.n_unmet
         if has_deps:
-            J = jobs.capacity
-            dec = completed[jnp.clip(jobs.dep_src, 0, J - 1)].astype(jnp.int32)
-            if csr is not None:
-                row_start, row_end = csr
-                c = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                     jnp.cumsum(dec)])
-                n_unmet = n_unmet - (c[row_end] - c[row_start])
-            else:
-                n_unmet = n_unmet.at[jobs.dep_dst].add(-dec, mode="drop")
+            with jax.named_scope("release"):
+                J = jobs.capacity
+                dec = completed[jnp.clip(jobs.dep_src, 0, J - 1)].astype(
+                    jnp.int32)
+                if csr is not None:
+                    row_start, row_end = csr
+                    c = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                         jnp.cumsum(dec)])
+                    n_unmet = n_unmet - (c[row_end] - c[row_start])
+                else:
+                    n_unmet = n_unmet.at[jobs.dep_dst].add(-dec, mode="drop")
         if rel is not None or svc is not None or mal_ticks:
             # stream events run after completions (a job finishing at the
             # failure/tick instant has completed) and before arrivals (a job
@@ -1123,14 +1127,16 @@ def _event_step(policy: jax.Array, jobs: JobSet, state: SimState,
             jstate, n_unmet = state.jstate, state.n_unmet
             arrived = (jstate == PENDING) & (jobs.submit <= clock)
             if has_deps:
-                arrived = arrived & (n_unmet == 0)
+                with jax.named_scope("release"):
+                    arrived = arrived & (n_unmet == 0)
             jstate = jnp.where(arrived, WAITING, jstate)
             state = dataclasses.replace(
                 state, jstate=jstate, n_events=state.n_events + 1)
         else:
             arrived = (jstate == PENDING) & (jobs.submit <= clock)
             if has_deps:
-                arrived = arrived & (n_unmet == 0)
+                with jax.named_scope("release"):
+                    arrived = arrived & (n_unmet == 0)
             jstate = jnp.where(arrived, WAITING, jstate)
 
             state = dataclasses.replace(

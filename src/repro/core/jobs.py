@@ -13,10 +13,13 @@ keeps every ``clock + estimate`` addition overflow-free).
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
 
 # Job lifecycle states (paper Fig. 1: submission -> waiting -> running -> done).
 PENDING = 0   # submitted to the simulator but its submit time is in the future
@@ -113,57 +116,71 @@ class JobSet:
         return jnp.sum(self.valid.astype(jnp.int32), axis=-1)
 
 
-def assert_acyclic(deps: np.ndarray) -> None:
-    """Kahn's algorithm over a dense bool dependency matrix; raises on
-    cycles.  ``deps[i, j]`` = *i* depends on *j*.  Shared by
-    ``make_jobset`` and ``repro.core.workflow.make_taskset``."""
-    n = deps.shape[0]
-    indeg = deps.sum(axis=1).astype(np.int64)
-    stack = list(np.nonzero(indeg == 0)[0])
+def _check_acyclic(dst: np.ndarray, src: np.ndarray, n: int) -> None:
+    """Kahn's algorithm over the edge list (``dst[e]`` depends on
+    ``src[e]``), one whole front of sources at a time; raises on cycles.
+    O(n + E) work, one NumPy pass per front."""
+    indeg = np.bincount(dst, minlength=n)
+    by_src = np.argsort(src, kind="stable")
+    out_dst = dst[by_src]
+    indptr = np.concatenate([[0], np.cumsum(np.bincount(src, minlength=n))])
+    front = np.flatnonzero(indeg == 0)
     seen = 0
-    dependents = [np.nonzero(deps[:, j])[0] for j in range(n)]
-    while stack:
-        j = stack.pop()
-        seen += 1
-        for i in dependents[j]:
-            indeg[i] -= 1
-            if indeg[i] == 0:
-                stack.append(i)
+    while front.size:
+        seen += front.size
+        lo, hi = indptr[front], indptr[front + 1]
+        lens = hi - lo
+        # the out-edges of every task of the front, as one index array
+        starts = np.repeat(lo - np.concatenate([[0], np.cumsum(lens)[:-1]]),
+                           lens)
+        heads = out_dst[starts + np.arange(lens.sum())]
+        np.subtract.at(indeg, heads, 1)
+        front = np.unique(heads[indeg[heads] == 0])
     if seen != n:
         raise ValueError("dependency graph contains a cycle")
 
 
-def _dense_deps(deps, n: int) -> np.ndarray:
-    """Normalize ``deps`` (pair list or dense matrix, pre-sort indices) to a
-    validated dense bool[n, n]; cycle-checked like ``make_taskset``.
+def assert_acyclic(deps: np.ndarray) -> None:
+    """Raise on a cycle of the dense bool dependency matrix ``deps``
+    (``deps[i, j]`` = *i* depends on *j*); used by
+    ``repro.core.workflow.make_taskset``."""
+    dst, src = np.nonzero(deps)
+    _check_acyclic(dst, src, deps.shape[0])
+
+
+def _dep_pairs(deps, n: int) -> tuple:
+    """Validated ``(job, dependency)`` index arrays of ``deps`` (a pair list,
+    an ``[E, 2]`` array or a dense matrix, pre-sort indices).
 
     A bool 2-D array is always a dense matrix (a wrong shape is an error,
     never re-parsed as pairs); other 2-D arrays are a matrix only at the
-    exact (n, n) shape, else a (job, dep) pair list.  Shared by
-    ``make_jobset`` and ``repro.refsim.ReferenceSimulator.load`` so both
-    engines accept bit-identical inputs.
+    exact (n, n) shape, and pairs wherever they have two columns (so an
+    ``[E, 2]`` edge array of a two-task table stays pairs).  Only a dense
+    matrix costs O(n²); pairs cost O(E).
     """
+    if not isinstance(deps, (list, tuple)) and not hasattr(deps, "shape"):
+        deps = list(deps)       # any other iterable of pairs
     mat = np.asarray(deps) if not isinstance(deps, (list, tuple)) else None
     is_dense = (mat is not None and mat.ndim == 2 and mat.dtype != object
-                and (mat.dtype == bool or mat.shape == (n, n)))
+                and (mat.dtype == bool
+                     or (mat.shape == (n, n) and mat.shape[1] != 2)))
     if is_dense:
         if mat.shape != (n, n):
             raise ValueError(
                 f"dense deps matrix has shape {mat.shape}, expected ({n}, {n})")
-        dense = mat.astype(bool)
-        if dense.diagonal().any():
-            raise ValueError("self-dependency")
+        t, d = np.nonzero(mat)
     else:
-        dense = np.zeros((n, n), dtype=bool)
-        for pair in deps:
-            t, d = int(pair[0]), int(pair[1])
-            if not (0 <= t < n and 0 <= d < n):
-                raise ValueError(f"dependency pair ({t},{d}) out of range")
-            if t == d:
-                raise ValueError("self-dependency")
-            dense[t, d] = True
-    assert_acyclic(dense)
-    return dense
+        pairs = np.asarray(deps if mat is None else mat,
+                           dtype=np.int64).reshape(-1, 2)
+        t, d = pairs[:, 0], pairs[:, 1]
+        bad = (t < 0) | (t >= n) | (d < 0) | (d >= n)
+        if bad.any():
+            i = int(np.argmax(bad))
+            raise ValueError(
+                f"dependency pair ({int(t[i])},{int(d[i])}) out of range")
+    if (t == d).any():
+        raise ValueError("self-dependency")
+    return t, d
 
 
 # Edge-list pads round up to this multiple so DAGs with nearby edge counts
@@ -173,14 +190,21 @@ _EDGE_ALIGN = 64
 
 def dep_edge_arrays(deps, n: int, order: np.ndarray) -> tuple:
     """Normalize ``deps`` to (dst, src) index arrays in *sorted-row*
-    coordinates, in (dst, src) lexicographic order.
+    coordinates, in (dst, src) lexicographic order, duplicate pairs once.
 
-    One shared path (validation + cycle check + sort permutation) for
-    ``make_jobset`` and ``repro.refsim.ReferenceSimulator.load``, so both
-    engines derive bit-identical edge sets from the same input.
+    O(n + E log E) on pairs: validation, the inverse of the (submit, id)
+    sort permutation ``order``, one sort of the packed keys, and Kahn's
+    cycle check over the result.  One shared path for ``make_jobset`` and
+    ``repro.refsim.ReferenceSimulator.load``, so both engines derive
+    bit-identical edge sets from the same input.
     """
-    dense = _dense_deps(deps, n)[order][:, order]
-    return np.nonzero(dense)
+    t, d = _dep_pairs(deps, n)
+    rank = np.empty(n, dtype=np.int64)
+    rank[order] = np.arange(n, dtype=np.int64)
+    key = np.unique(rank[t] * n + rank[d])
+    dst, src = key // n, key % n
+    _check_acyclic(dst, src, n)
+    return dst, src
 
 
 def make_jobset(
@@ -202,14 +226,16 @@ def make_jobset(
       larger than the simulated machine; CQsim clamps the same way),
     - pads to ``capacity`` with invalid rows.
 
-    ``deps`` is either an iterable of ``(job, dependency)`` index pairs or a
-    dense bool matrix, both in *input* order (indices into ``submit``); it is
-    cycle-checked, permuted into the sorted row order, and lowered to the
-    padded ``dep_dst``/``dep_src`` edge list (length rounded up to a multiple
-    of 64, or exactly ``edge_capacity`` when given; padding slots hold the
-    out-of-range index ``capacity``).  An empty or all-False ``deps`` is
-    elided to ``None`` so the no-dependency case compiles to the exact seed
-    path.
+    ``deps`` is either an iterable of ``(job, dependency)`` index pairs (an
+    ``[E, 2]`` array among them) or a dense bool matrix, both in *input*
+    order (indices into ``submit``); it is cycle-checked, permuted into the
+    sorted row order, and lowered to the padded ``dep_dst``/``dep_src`` edge
+    list (length rounded up to a multiple of 64, or exactly
+    ``edge_capacity`` when given; padding slots hold the out-of-range index
+    ``capacity``) in O(J + E) on the host (span ``repro.deps.lower``,
+    counters ``deps.edges`` and ``deps.lower_us``).  An empty or all-False
+    ``deps`` is elided to ``None`` so the no-dependency case compiles to the
+    exact seed path.
     """
     submit = np.asarray(submit, dtype=np.int64)
     runtime = np.asarray(runtime, dtype=np.int64)
@@ -248,7 +274,13 @@ def make_jobset(
 
     dep_dst = dep_src = None
     if deps is not None:
-        dst, src = dep_edge_arrays(deps, n, order)
+        with obs.span("deps.lower", tasks=n) as span:
+            t0 = time.perf_counter()
+            dst, src = dep_edge_arrays(deps, n, order)
+            lower_us = int((time.perf_counter() - t0) * 1e6)
+            span.set_metadata(edges=int(dst.size))
+        obs.count("deps.edges", int(dst.size))
+        obs.count("deps.lower_us", lower_us)
         n_edges = int(dst.size)
         if n_edges:
             if edge_capacity is None:

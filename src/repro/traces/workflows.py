@@ -11,6 +11,9 @@ Workflows"; Pegasus workflow gallery):
   final mosaic merge (paper Fig. 6 runs this at scale).
 - SIPHT: parallel sRNA prediction chains (Patser x W -> concat), several
   independent annotation tasks, final sRNA annotate (paper Fig. 7).
+- Montage campaign: mosaics of the published Montage 8-degree profile
+  (10,429 tasks each) submitted at a fixed gap onto one slot pool, as the
+  Galactic Plane atlas (Rynge et al. 2013) runs them.
 
 All generators return plain dicts compatible with ``make_taskset`` /
 ``simulate_workflow_reference`` and the JSON round-trip below.
@@ -88,6 +91,10 @@ def random_layered(
 
 
 def montage_like(width: int = 20, *, seed: int = 0) -> WorkflowDict:
+    """A reduced Montage shape for tests and small DAGs, not the published
+    profile: ``width`` images, ``width - 1`` diff pairs of neighbours, one
+    mImgtbl/mAdd/mShrink, a 2-core mBgModel and a 4-core mAdd, uniform
+    runtimes.  :func:`montage_campaign` is the profile at its own sizes."""
     rng = np.random.default_rng(seed)
     et, cpu, mem, deps = [], [], [], []
 
@@ -162,6 +169,108 @@ def sipht_like(width: int = 30, *, seed: int = 0) -> WorkflowDict:
     return _mk(et, cpu, mem, deps)
 
 
+# a campaign's time unit in seconds (0.66-s and 1.73-s stages stay apart,
+# and most completions still share an event), and the spread of a stage's
+# runtimes around its published mean; both assumed, neither published
+CAMPAIGN_TICK_S = 0.1
+CAMPAIGN_CV = 0.3
+
+
+def _grid_pairs(images: int, pairs: int) -> np.ndarray:
+    """``int64[pairs, 2]`` overlapping image pairs: the images fill a
+    square grid row by row (the last row short), and the pairs are every
+    horizontal neighbour, then every vertical one, then the diagonals
+    (down-right, then down-left, each row by row) until there are
+    ``pairs``."""
+    side = int(np.ceil(np.sqrt(images)))
+    r, c = np.divmod(np.arange(images), side)
+    idx = np.full((side + 1, side + 2), -1, dtype=np.int64)
+    idx[r, c + 1] = np.arange(images)
+    out = []
+    for dr, dc in ((0, 1), (1, 0), (1, 1), (1, -1)):
+        a = idx[r, c + 1]
+        b = idx[r + dr, c + 1 + dc]
+        keep = b >= 0
+        out.append(np.stack([a[keep], b[keep]], axis=1))
+    cand = np.concatenate(out)
+    if len(cand) < pairs:
+        raise ValueError(f"{images} images on a {side}x{side} grid overlap "
+                         f"in at most {len(cand)} pairs, not {pairs}")
+    return cand[:pairs]
+
+
+def montage_campaign(mosaics: int = 10, *, images: int = 2102,
+                     pairs: int = 6172, tiles: int = 16, gap: int = 220,
+                     project_s: float = 1.73, diff_s: float = 0.66,
+                     concat_s: float = 143.26, bgmodel_s: float = 384.49,
+                     background_s: float = 1.72, imgtbl_s: float = 2.78,
+                     add_s: float = 282.37, shrink_s: float = 66.10,
+                     jpeg_s: float = 0.64, seed: int = 0) -> WorkflowDict:
+    """A campaign of ``mosaics`` Montage mosaics, mosaic *k* submitted at
+    ``k * gap`` ticks.  Each follows the published profile of the Montage
+    8-degree workflow (Juve et al., "Characterizing and Profiling Scientific
+    Workflows", FGCS 29(3), 2013): 2,102 mProjectPP, 6,172 mDiffFit, one
+    mConcatFit and mBgModel, 2,102 mBackground, 17 mImgtbl, 17 mAdd, 16
+    mShrink and one mJPEG, with the stages' mean runtimes as defaults.
+
+    Per mosaic: each mDiffFit waits on its two overlapping mProjectPP
+    (:func:`_grid_pairs`), mConcatFit on every mDiffFit, mBgModel on
+    mConcatFit, mBackground *i* on mProjectPP *i* and mBgModel; the images
+    split into ``tiles`` square tiles, and each tile's mImgtbl waits on its
+    images' mBackground, its mAdd on that mImgtbl, its mShrink on that mAdd;
+    a last mImgtbl waits on every mShrink, a last mAdd on it, mJPEG on that.
+    ``3 * (images + pairs + tiles + 1)`` edges a mosaic (24,873 at the
+    defaults, of 10,429 tasks).  The layout is fixed by the shape; ``seed``
+    draws only the runtimes: lognormal around each stage's mean with a
+    coefficient of variation of ``CAMPAIGN_CV``, in ticks of
+    ``CAMPAIGN_TICK_S`` seconds, at least one tick.  Every task takes one core.  Edges are ``int32[E, 2]``.
+    """
+    k = int(round(np.sqrt(tiles)))
+    if k * k != tiles:
+        raise ValueError(f"tiles must be a square number, not {tiles}")
+    side = int(np.ceil(np.sqrt(images)))
+    pair = _grid_pairs(images, pairs)
+    img = np.arange(images)
+    r, c = np.divmod(img, side)
+    tile = (r * k // side) * k + c * k // side
+
+    # task ids of one mosaic, stage after stage
+    counts = (images, pairs, 1, 1, images, tiles + 1, tiles + 1, tiles, 1)
+    first = np.concatenate([[0], np.cumsum(counts)])
+    proj, diff, concat, bgm, back, imgt, madd, shr, jpeg = (
+        first[i] + np.arange(counts[i]) for i in range(len(counts)))
+    n = int(first[-1])
+
+    def fan(dst, src):
+        dst, src = np.broadcast_arrays(dst, src)
+        return np.stack([dst, src], axis=1)
+
+    edges = np.concatenate([
+        fan(diff, proj[pair[:, 0]]), fan(diff, proj[pair[:, 1]]),
+        fan(concat[0], diff), fan(bgm, concat),
+        fan(back, proj), fan(back, bgm[0]),
+        fan(imgt[tile], back),
+        fan(madd[:tiles], imgt[:tiles]), fan(shr, madd[:tiles]),
+        fan(imgt[tiles], shr), fan(madd[tiles:], imgt[tiles:]),
+        fan(jpeg, madd[tiles:]),
+    ])
+    means = np.repeat([project_s, diff_s, concat_s, bgmodel_s, background_s,
+                       imgtbl_s, add_s, shrink_s, jpeg_s], counts)
+    means = means / CAMPAIGN_TICK_S
+    sigma = np.sqrt(np.log1p(CAMPAIGN_CV ** 2))
+
+    rng = np.random.default_rng(seed)
+    draws = rng.lognormal(np.log(means) - sigma * sigma / 2, sigma,
+                          (mosaics, n))
+    offset = (np.arange(mosaics) * n)[:, None, None]
+    return {
+        "exec_time": np.maximum(np.rint(draws), 1).astype(np.int64).ravel(),
+        "resources": np.tile([1, 0], (mosaics * n, 1)).astype(np.int64),
+        "dep_pairs": (edges[None] + offset).reshape(-1, 2).astype(np.int32),
+        "submit": np.repeat(np.arange(mosaics, dtype=np.int64) * gap, n),
+    }
+
+
 # ---------------------------------------------------------------------------
 # lowering: workflow DAG -> cluster job trace (DESIGN.md §13)
 # ---------------------------------------------------------------------------
@@ -173,11 +282,13 @@ def workflow_to_trace(wf: WorkflowDict, *, submit: int = 0,
     Tasks become cluster jobs: ``exec_time`` -> runtime/estimate, the cpu
     requirement (``resources[:, 0]``) -> node count (memory is a pool-model
     resource with no cluster analogue and is dropped), and the DAG edges
-    ride along as ``deps`` pairs for ``make_jobset``.  Every task shares one
-    ``submit`` time — release order is driven purely by the dependency
-    structure, so wait = start - ready isolates queueing delay (paper
-    Fig. 7).  ``priority="cpath"`` attaches critical-path-length priorities
-    (longest path first) for the ``preempt`` policy.
+    ride along as an ``int32[E, 2]`` ``deps`` array for ``make_jobset``.
+    Every task is submitted at ``submit``, plus its own offset where the
+    workflow carries a ``submit`` column (a campaign's mosaics) — release
+    order within a workflow is driven purely by the dependency structure,
+    so wait = start - ready isolates queueing delay (paper Fig. 7).
+    ``priority="cpath"`` attaches critical-path-length priorities (longest
+    path first) for the ``preempt`` policy.
     """
     et = np.asarray(wf["exec_time"], dtype=np.int64)
     nodes = np.asarray(wf["resources"], dtype=np.int64)
@@ -185,11 +296,12 @@ def workflow_to_trace(wf: WorkflowDict, *, submit: int = 0,
         nodes = nodes[:, 0]
     n = len(et)
     trace: Dict[str, object] = {
-        "submit": np.full(n, int(submit), dtype=np.int64),
+        "submit": int(submit) + np.asarray(wf.get("submit", np.zeros(n)),
+                                           dtype=np.int64),
         "runtime": et.copy(),
         "estimate": et.copy(),
         "nodes": np.maximum(nodes, 1),
-        "deps": [(int(t), int(d)) for t, d in wf["dep_pairs"]],
+        "deps": np.asarray(wf["dep_pairs"], dtype=np.int32).reshape(-1, 2),
     }
     if priority == "cpath":
         from repro.core.workflow import critical_path_length

@@ -24,9 +24,7 @@ from _hypothesis_compat import given, settings, st
 from repro.api import Scenario, Topology, WorkflowTrace, run, run_ref
 from repro.core import engine
 from repro.core.engine import _simulate_jit, make_alloc_ctx, simulate
-from repro.core.jobs import (
-    POLICY_IDS, _dense_deps, make_jobset,
-)
+from repro.core.jobs import POLICY_IDS, make_jobset
 from repro.core.parallel import simulate_ensemble, stack_jobsets
 from repro.traces.workflows import (
     galactic_like, montage_like, random_layered, workflow_to_trace,
@@ -63,10 +61,13 @@ def test_edge_list_round_trips_the_dense_matrix():
     n = len(trace["submit"])
     jobs = make_jobset(trace["submit"], trace["runtime"], trace["nodes"],
                        trace["estimate"], deps=trace["deps"], total_nodes=8)
-    # reference: the dense normalizer permuted by the (submit, id) sort —
-    # exactly what the removed dense field used to hold
+    # reference: the dense matrix of the pairs permuted by the (submit, id)
+    # sort — exactly what the removed dense field used to hold
     order = np.lexsort((np.arange(n), np.asarray(trace["submit"])))
-    want = _dense_deps(trace["deps"], n)[order][:, order]
+    dense = np.zeros((n, n), dtype=bool)
+    for t, d in trace["deps"]:
+        dense[t, d] = True
+    want = dense[order][:, order]
     got = np.asarray(jobs.deps)  # property reconstructs from the edge list
     np.testing.assert_array_equal(got[:n, :n], want)
     assert not got[n:].any() and not got[:, n:].any()
