@@ -32,11 +32,14 @@ is the free counter (scalar-counter mode, or the count-capped
 entire feasible prefix off a loop-invariant queue permutation (one sort
 per *call*, one O(J) cumsum per event) instead of re-running the policy
 selector after every start — DAG stage fronts start whole release waves
-in a single event.  Dependency-free tables, backfill, bestfit, preempt
-and the geometry-capped strategies keep the per-start loop (with the
-selector dispatched statically where known); the choice is made at trace
-time (a traced policy — e.g. a ``vmap``-ped sweep axis — always takes the
-seed loop), so no path pays for another's.
+in a single event.  When that permutation is the identity (a
+``make_jobset`` table, FCFS or EASY's FCFS phase) and the cap is the
+scalar counter, the pass reads the queue in row order and applies the
+starts as masks: no gather and no loop.  Dependency-free tables, backfill,
+bestfit, preempt and the geometry-capped strategies keep the per-start
+loop (with the selector dispatched statically where known); the choice is
+made at trace time (a traced policy — e.g. a ``vmap``-ped sweep axis —
+always takes the seed loop), so no path pays for another's.
 
 Malleable jobs (DESIGN.md §17): with a ``malleable`` plan the engine gains
 moldable width choice at dispatch — among placement-feasible widths the
@@ -84,6 +87,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import alloc as _alloc
+from repro import obs
 from repro.core import policies
 from repro.core.jobs import (
     BACKFILL, DONE, FCFS, INF_TIME, LJF, PENDING, PREEMPT, RUNNING, SJF,
@@ -362,6 +366,13 @@ def _select(policy: jax.Array, jobs: JobSet, state: SimState,
                            static_policy=static_policy)
 
 
+# The batched pass's two ways to read the queue: in row order, where the
+# table's rows are already in blocking order (``ROW_ORDER`` also stands in
+# for the permutation as ``fast_order``), or through ``blocking_order``
+ROW_ORDER = "row_order"
+PERMUTED = "permuted"
+
+
 def blocking_order(jobs: JobSet, static_policy: int) -> jax.Array:
     """Loop-invariant queue permutation for a blocking policy.
 
@@ -377,8 +388,38 @@ def blocking_order(jobs: JobSet, static_policy: int) -> jax.Array:
     return jnp.argsort(key, stable=True)
 
 
+def _row_order_pass(jobs: JobSet, state: SimState) -> SimState:
+    """``_batched_pass`` on a table whose row order is its blocking order
+    (``ROW_ORDER``), in scalar-counter mode: no gather, no loop.
+
+    The queue is read in row order, and the whole feasible prefix starts
+    at once as masked updates of exactly the leaves ``place_slim``
+    (``place_slim_rel``) writes one start at a time — the starts touch
+    distinct rows and only subtract from ``free``, so applying them
+    together is bit-identical to applying them in turn (DESIGN.md §14.2).
+    """
+    waiting = state.jstate == WAITING
+    cum = jnp.cumsum(jnp.where(waiting, jobs.nodes, 0))
+    take = waiting & (cum <= state.free)      # longest feasible prefix
+    t0 = state.clock
+    new = dataclasses.replace(
+        state,
+        jstate=jnp.where(take, RUNNING, state.jstate),
+        start=jnp.where(take, jnp.minimum(state.start, t0), state.start),
+        finish=jnp.where(take, t0 + state.remaining, state.finish),
+        rsv_finish=jnp.where(take, t0 + jobs.estimate, state.rsv_finish),
+        free=state.free - jnp.sum(jnp.where(take, jobs.nodes, 0)),
+    )
+    if state.rel is None:
+        return new
+    # reliability: every dispatch stamps its checkpoint base (DESIGN.md §15)
+    return dataclasses.replace(new, rel=dataclasses.replace(
+        state.rel,
+        last_start=jnp.where(take, t0, state.rel.last_start)))
+
+
 def _batched_pass(jobs: JobSet, state: SimState, ctx: Optional[AllocCtx],
-                  order: jax.Array) -> SimState:
+                  order: jax.Array | str) -> SimState:
     """Start the whole feasible prefix of the waiting queue in one shot.
 
     For a blocking head-of-queue policy with a free-counter feasibility cap,
@@ -392,8 +433,12 @@ def _batched_pass(jobs: JobSet, state: SimState, ctx: Optional[AllocCtx],
     cumsum strictly increases across waiting rows, so masking with the
     waiting flag yields exactly the sequential prefix.)  The starts are
     then applied selector-free, in key order; with a count-capped strategy
-    the same loop additionally runs each job's node placement.
+    the same loop additionally runs each job's node placement.  ``order``
+    is ``ROW_ORDER`` when the permutation is the identity
+    (``_row_order_pass``).
     """
+    if isinstance(order, str):
+        return _row_order_pass(jobs, state)
     waiting = state.jstate == WAITING
     w_sorted = waiting[order]
     cum = jnp.cumsum(jnp.where(w_sorted, jobs.nodes[order], 0))
@@ -401,11 +446,11 @@ def _batched_pass(jobs: JobSet, state: SimState, ctx: Optional[AllocCtx],
     n_take = jnp.cumsum(take.astype(jnp.int32))
     n_started = n_take[-1]
 
-    # Apply the starts one row at a time: the i-th started row is found by
-    # binary search on the running take-count (scatter-free compaction),
-    # and each start is a handful of single-element in-place updates — far
-    # cheaper on XLA:CPU than rewriting four J-sized arrays with masked
-    # `where`s on every event.
+    # Apply the starts one row at a time, in key order: the i-th started
+    # row is found by binary search on the running take-count
+    # (scatter-free compaction).  Placement needs the order; in scalar
+    # mode the loop stands in for a scatter through the permutation
+    # (a table in row order takes ``_row_order_pass`` instead).
     if ctx is not None:
         # allocation mode: placements mutate the node map, so reuse the
         # full `_start_job` (the fori carries the whole state)
@@ -471,7 +516,7 @@ def _batched_pass(jobs: JobSet, state: SimState, ctx: Optional[AllocCtx],
 
 def _batched_backfill_pass(jobs: JobSet, state: SimState,
                            ctx: Optional[AllocCtx],
-                           order: jax.Array) -> SimState:
+                           order: jax.Array | str) -> SimState:
     """One whole EASY-backfill scheduling pass per event (DESIGN.md §18).
 
     Phase A — the blocking prefix: EASY starts the FCFS head while it
@@ -575,7 +620,7 @@ def _batched_backfill_pass(jobs: JobSet, state: SimState,
 def _schedule_pass(policy: jax.Array, jobs: JobSet, state: SimState,
                    ctx: Optional[AllocCtx],
                    static_policy: Optional[int] = None,
-                   fast_order: Optional[jax.Array] = None,
+                   fast_order: Optional[jax.Array | str] = None,
                    mctx: Optional[tuple] = None) -> SimState:
     """Start jobs until the policy blocks (Algorithm 1 lines 16-21).
 
@@ -1028,7 +1073,7 @@ def _process_mal_ticks(jobs: JobSet, state: SimState,
 def _event_step(policy: jax.Array, jobs: JobSet, state: SimState,
                 ctx: Optional[AllocCtx] = None,
                 static_policy: Optional[int] = None,
-                fast_order: Optional[jax.Array] = None,
+                fast_order: Optional[jax.Array | str] = None,
                 csr: Optional[tuple] = None,
                 rel: Optional[tuple] = None,
                 svc: Optional[tuple] = None,
@@ -1285,6 +1330,9 @@ def simulate(
                 f"the job-table capacity ({jobs.capacity}); materialize "
                 "the plan with capacity == the padded job capacity")
     static_strategy = _concrete_int(ctx[1]) if ctx is not None else None
+    if mctx is None:
+        _count_pass_kind(_batched_pass_kind(jobs, ctx, static_policy,
+                                            static_strategy))
     return _simulate_jit(
         jobs, jnp.asarray(policy, dtype=jnp.int32),
         jnp.asarray(total_nodes, dtype=jnp.int32), ctx, fctx=fctx,
@@ -1369,12 +1417,43 @@ def _simulate_jit(
         nref=None if mctx is None else mctx[1])
 
 
+def _batched_pass_kind(jobs: JobSet, ctx: Optional[AllocCtx],
+                       static_policy: Optional[int],
+                       static_strategy: Optional[int]) -> Optional[str]:
+    """How the scheduling pass runs, from static facts alone: ``ROW_ORDER``
+    (batched, queue read in row order), ``PERMUTED`` (batched, queue read
+    through ``blocking_order``) or ``None`` (per-start selector loop).
+
+    Row order needs scalar-counter mode (placement in machine mode walks
+    the starts one by one anyway), a submit-keyed blocking order (FCFS, or
+    EASY's phase A) and a table whose ``submit_sorted`` says that order is
+    the identity.  See ``_fast_order`` for the batched-pass eligibility.
+    """
+    if not (ctx is None or static_strategy in _COUNT_CAPPED):
+        return None
+    if static_policy != BACKFILL and not (
+            jobs.dep_dst is not None and static_policy in _BLOCKING_POLICIES):
+        return None
+    if ctx is None and jobs.submit_sorted and static_policy in (FCFS,
+                                                                BACKFILL):
+        return ROW_ORDER
+    return PERMUTED
+
+
+def _count_pass_kind(kind: Optional[str]) -> None:
+    """Counters ``engine.batched.row_order`` / ``engine.batched.permuted``
+    (DESIGN.md §21.3): one per host call (per trace under a caller's
+    jit)."""
+    if kind is not None:
+        obs.count("engine.batched." + kind)
+
+
 def _fast_order(jobs: JobSet, ctx: Optional[AllocCtx],
                 static_policy: Optional[int],
-                static_strategy: Optional[int]) -> Optional[jax.Array]:
-    """The loop-invariant batched-pass permutation, or ``None`` when the
-    combination keeps the per-start selector loop (DESIGN.md §14
-    eligibility table).
+                static_strategy: Optional[int]) -> Optional[jax.Array | str]:
+    """The loop-invariant batched-pass permutation, ``ROW_ORDER`` when that
+    permutation is the identity, or ``None`` when the combination keeps the
+    per-start selector loop (DESIGN.md §14 eligibility table).
 
     The batched pass needs a blocking policy and a free-counter feasibility
     cap, and it only *pays* on workloads whose events start many jobs at
@@ -1393,13 +1472,10 @@ def _fast_order(jobs: JobSet, ctx: Optional[AllocCtx],
     2.0k -> 28.8k ev/s on the congested no-deps SDSC-like case, where
     nearly every event has a blocked head).
     """
-    if static_policy == BACKFILL \
-            and (ctx is None or static_strategy in _COUNT_CAPPED):
+    kind = _batched_pass_kind(jobs, ctx, static_policy, static_strategy)
+    if kind == PERMUTED:
         return blocking_order(jobs, static_policy)
-    if jobs.dep_dst is not None and static_policy in _BLOCKING_POLICIES \
-            and (ctx is None or static_strategy in _COUNT_CAPPED):
-        return blocking_order(jobs, static_policy)
-    return None
+    return kind
 
 
 def next_event_time(jobs: JobSet, state: SimState) -> jax.Array:
@@ -1445,6 +1521,8 @@ def simulate_window(
     """
     static_policy = _static_policy_hint(policy)
     static_strategy = _concrete_int(ctx[1]) if ctx is not None else None
+    _count_pass_kind(_batched_pass_kind(jobs, ctx, static_policy,
+                                        static_strategy))
     fast_order = _fast_order(jobs, ctx, static_policy, static_strategy)
 
     def next_due(st: SimState):

@@ -75,6 +75,15 @@ class JobSet:
     path) for plain job traces; being pytree leaves they batch through
     ``vmap`` ensembles and ``sweep()`` like any other job attribute
     (``stack_jobsets`` pads ragged edge counts to one shape).
+
+    ``submit_sorted`` is a static (pytree aux) fact, not a leaf: True when
+    ``submit`` is non-decreasing down the rows, so the stable submit-order
+    permutation is the identity and row order IS FCFS order.  Only
+    ``make_jobset`` sets it (it sorts by (submit, index) and pads with
+    ``INF_TIME`` at the tail); every other constructor leaves it False, and
+    an edit that moves submits or rows must clear it.  The engine's batched
+    scheduling pass reads the queue in row order when it is set (DESIGN.md
+    §14).
     """
 
     submit: jax.Array    # i32[J]
@@ -85,6 +94,8 @@ class JobSet:
     valid: jax.Array     # bool[J]
     dep_dst: jax.Array | None = None  # i32[E] dependent row  (capacity = pad)
     dep_src: jax.Array | None = None  # i32[E] dependency row (capacity = pad)
+    submit_sorted: bool = dataclasses.field(default=False,
+                                            metadata=dict(static=True))
 
     @property
     def capacity(self) -> int:
@@ -221,7 +232,9 @@ def make_jobset(
 ) -> JobSet:
     """Build a normalized ``JobSet`` from host arrays.
 
-    - sorts by (submit, original index) so row order == FCFS order,
+    - sorts by (submit, original index) so row order == FCFS order, and
+      says so in ``submit_sorted`` (padding rows hold ``INF_TIME``, so the
+      order holds over the whole padded table),
     - clamps node requests to ``total_nodes`` (paper traces contain requests
       larger than the simulated machine; CQsim clamps the same way),
     - pads to ``capacity`` with invalid rows.
@@ -311,6 +324,7 @@ def make_jobset(
         valid=jnp.asarray(valid),
         dep_dst=None if dep_dst is None else jnp.asarray(dep_dst),
         dep_src=None if dep_src is None else jnp.asarray(dep_src),
+        submit_sorted=True,
     )
 
 

@@ -47,8 +47,12 @@ def stack_jobsets(jobsets: list[JobSet]) -> JobSet:
     every member is padded to the longest edge list with inert out-of-range
     edges (index = capacity, the same padding ``make_jobset`` emits), so the
     stacked pytree is uniform.  Padding edges scatter out of bounds and
-    drop, so schedules are unchanged.
+    drop, so schedules are unchanged.  The stack keeps ``submit_sorted``
+    only where every member has it.
     """
+    if not all(j.submit_sorted for j in jobsets):
+        jobsets = [dataclasses.replace(j, submit_sorted=False)
+                   for j in jobsets]
     if any(j.dep_dst is not None for j in jobsets):
         ecap = max(j.edge_capacity for j in jobsets)
 
@@ -330,6 +334,9 @@ def simulate_multicluster(
     """
     C = jobs_c.submit.shape[0]
     J = jobs_c.submit.shape[1]
+    # imports land in free rows with later submits, so row order stops
+    # being FCFS order after the first migration round
+    jobs_c = dataclasses.replace(jobs_c, submit_sorted=False)
     policy = jnp.asarray(policy, dtype=jnp.int32)
     nodes_c = jnp.asarray(nodes_c, dtype=jnp.int32)
     latency = int(latency if latency is not None else window)

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro import obs
 from repro.api import Scenario, Topology, WorkflowTrace, run, run_ref
 from repro.core import engine
 from repro.core.engine import _simulate_jit, make_alloc_ctx, simulate
@@ -424,7 +425,9 @@ def test_failures_none_hlo_identical_to_pre_reliability_head():
     with the same jax, the commit before the reliability subsystem gives
     the pinned hash for every configuration except the four backfill ones
     and the dynamic-policy one, which the batched backfill pass (DESIGN.md
-    §18) changed on purpose.
+    §18) changed on purpose; the row-order pass (DESIGN.md §14) changed
+    ``dag_scalar_fcfs``, ``dag_scalar_backfill`` and
+    ``plain_scalar_backfill`` on purpose since.
 
     Regenerate the fixture ONLY for intentional engine-graph changes:
     ``PYTHONPATH=src:tests python tests/_hlo_fixture.py --write``.
@@ -496,3 +499,125 @@ def test_traced_policy_keeps_seed_semantics_under_vmap():
         single = simulate(jobs, POLICY_IDS[p], 8)
         np.testing.assert_array_equal(np.asarray(batched.start)[i],
                                       np.asarray(single.start), p)
+
+
+# ---------------------------------------------------------------------------
+# row-order batched pass (DESIGN.md §14): a make_jobset table's row order is
+# its FCFS order, so the pass reads the queue in place and applies the starts
+# as masks — bit-identical to the gather-and-loop pass over blocking_order
+# ---------------------------------------------------------------------------
+
+
+def _pass_counts():
+    c = obs.counters()
+    return (c.get("engine.batched.row_order", 0),
+            c.get("engine.batched.permuted", 0))
+
+
+def _row_order_case(case):
+    """(trace, policy, total_nodes, failure trace or None) for one case."""
+    from repro.api import FailureModel
+
+    if case.startswith("fcfs_dag"):
+        seed = int(case.rsplit("_", 1)[1])
+        t = workflow_to_trace(random_layered(40, 5, p_edge=0.25, seed=seed))
+        return dict(t), "fcfs", 8, None
+    if case == "montage_campaign":
+        wf = WorkflowTrace(kind="montage_campaign", seed=3,
+                           params=(("images", 40), ("mosaics", 2),
+                                   ("pairs", 110), ("tiles", 4)))
+        return dict(wf.materialize()), "fcfs", 32, None
+    if case == "galactic_failures":
+        t = workflow_to_trace(galactic_like(tiles=2, width=5, seed=4))
+        return dict(t), "fcfs", 16, FailureModel(**BF_FAIL).materialize(16)
+    if case.startswith("easy"):
+        fail = case.endswith("failures")
+        ftrace = FailureModel(**BF_FAIL).materialize(16) if fail else None
+        return _bf_trace(False), BF, 16, ftrace
+    assert case == "sjf"
+    t = workflow_to_trace(galactic_like(tiles=2, width=5, seed=1))
+    return dict(t), "sjf", 8, None
+
+
+@pytest.mark.parametrize("case", (
+    "fcfs_dag_0", "fcfs_dag_1", "fcfs_dag_2", "montage_campaign",
+    "galactic_failures", "easy_scalar", "easy_scalar_failures", "sjf"))
+def test_row_order_pass_equals_gather_pass_and_refsim(case):
+    """The row-order pass, the gather-and-loop pass over ``blocking_order``
+    (the same table without ``submit_sorted``) and the host reference agree
+    bit for bit; the counters name the pass each call took (SJF keys on
+    the estimate, so it keeps the gathers)."""
+    from repro.refsim import simulate_reference
+
+    trace, policy, nodes, ftrace = _row_order_case(case)
+    jobs = make_jobset(trace["submit"], trace["runtime"], trace["nodes"],
+                       trace.get("estimate"), deps=trace.get("deps"),
+                       total_nodes=nodes)
+    assert jobs.submit_sorted
+    gathered = dataclasses.replace(jobs, submit_sorted=False)
+    row0, perm0 = _pass_counts()
+    a = simulate(jobs, POLICY_IDS[policy], nodes, failures=ftrace)
+    row1, perm1 = _pass_counts()
+    b = simulate(gathered, POLICY_IDS[policy], nodes, failures=ftrace)
+    row2, perm2 = _pass_counts()
+    row_order = policy != "sjf"
+    assert (row1 - row0, perm1 - perm0) == ((1, 0) if row_order else (0, 1))
+    assert (row2 - row1, perm2 - perm1) == (0, 1)
+    _assert_same(a, b, msg=case)
+    assert int(a.n_events) == int(b.n_events)
+    ref = simulate_reference(trace, policy, total_nodes=nodes,
+                             failures=ftrace)
+    n = len(trace["submit"])
+    for f in ("start", "finish", "ready"):
+        np.testing.assert_array_equal(np.asarray(getattr(a, f))[:n], ref[f],
+                                      err_msg=f"{case}:ref:{f}")
+    assert int(a.n_events) == int(ref["n_events"])
+
+
+@settings(max_examples=40, deadline=None)
+@given(seed=st.integers(0, 2**31 - 1), n=st.integers(1, 40),
+       pad=st.integers(0, 9), span=st.sampled_from([1, 3, 50]))
+def test_make_jobset_rows_are_in_blocking_order(seed, n, pad, span):
+    """Property: every ``make_jobset`` table — tied submits and padding
+    rows included — has FCFS's and EASY's blocking order equal to its row
+    order, which is the premise ``submit_sorted`` states."""
+    rng = np.random.default_rng(seed)
+    jobs = make_jobset(rng.integers(0, span, n), rng.integers(1, 30, n),
+                       rng.integers(1, 5, n), capacity=n + pad,
+                       total_nodes=8)
+    assert jobs.submit_sorted
+    rows = np.arange(n + pad)
+    for policy in ("fcfs", BF):
+        np.testing.assert_array_equal(
+            np.asarray(engine.blocking_order(jobs, POLICY_IDS[policy])), rows)
+
+
+def test_stack_jobsets_keeps_row_order_only_when_every_member_has_it():
+    jobs = make_jobset([0, 1, 1], [5, 5, 5], [1, 2, 1], total_nodes=4)
+    other = dataclasses.replace(jobs, submit_sorted=False)
+    assert stack_jobsets([jobs, jobs]).submit_sorted
+    assert not stack_jobsets([jobs, other]).submit_sorted
+
+
+def test_replay_and_multicluster_tables_never_take_the_row_order_pass():
+    """Replay windows and multicluster tables are built outside
+    ``make_jobset`` (or lose its order to migration), so their backfill
+    pass keeps the gathers: the counters see ``permuted`` and never
+    ``row_order``."""
+    from repro.core.parallel import simulate_multicluster
+    from repro.replay import replay_trace
+
+    trace = _bf_trace(False)
+    row0, perm0 = _pass_counts()
+    replay_trace(dict(trace), BF, total_nodes=16, window=24)
+    row1, perm1 = _pass_counts()
+    assert row1 == row0 and perm1 > perm0
+    js = [make_jobset(trace["submit"], trace["runtime"], trace["nodes"],
+                      trace["estimate"], capacity=80, total_nodes=16)
+          for _ in range(2)]
+    res = simulate_multicluster(stack_jobsets(js), POLICY_IDS[BF],
+                                np.full((2,), 16, np.int32), window=100,
+                                horizon=600)
+    row2, perm2 = _pass_counts()
+    assert row2 == row1 and perm2 > perm1
+    assert not res.jobs.submit_sorted

@@ -9,8 +9,10 @@ the same tests and only the worker that runs this file loads the TPU
 library.
 """
 
+import dataclasses
 import itertools
 import os
+import re
 import sys
 
 import jax
@@ -22,7 +24,9 @@ from jax.sharding import SingleDeviceSharding
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import chip_smoke  # noqa: E402
-from repro.api import Scenario, SyntheticTrace, build_jobset  # noqa: E402
+from repro.api import (  # noqa: E402
+    Scenario, SyntheticTrace, WorkflowTrace, build_jobset,
+)
 from repro.api.sweep import _bucket_fn, _bucket_program  # noqa: E402
 from repro.core import engine  # noqa: E402
 from repro.kernels.queue_select.kernel import queue_select_tiled  # noqa: E402
@@ -118,3 +122,56 @@ def test_queue_select_kernel_compiles_1m(one_chip):
     compiled = jax.jit(lambda s, f: queue_select_tiled(
         s, f, tile=8192, interpret=False)).lower(x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# an HLO instruction: its opcode, and the op_name of its metadata
+_HLO_OP = re.compile(
+    r'^\s*(?:ROOT )?\S+ = (\S+|\(.*?\)) ([a-z][a-z0-9-]*)\(.*'
+    r'op_name="([^"]*)"')
+
+
+def _schedule_ops(scn, sharding, row_order):
+    """(opcode, shape, op_name) of every compiled instruction whose
+    op_name lies under the engine's ``schedule`` scope."""
+    jobs = build_jobset(scn)
+    if not row_order:
+        jobs = dataclasses.replace(jobs, submit_sorted=False)
+    policy = engine.policies_id(scn.policy)
+    args = _shapes((jobs, jnp.asarray(policy, jnp.int32),
+                    jnp.asarray(int(scn.total_nodes), jnp.int32), None),
+                   sharding)
+    text = engine._simulate_jit.lower(
+        *args, fctx=None, static_policy=engine._static_policy_hint(policy),
+        static_strategy=None).compile().as_text()
+    ops = [m.groups() for m in map(_HLO_OP.match, text.splitlines()) if m]
+    assert ops
+    return jobs.capacity, [(op, shape, name) for shape, op, name in ops
+                           if "/schedule/" in name]
+
+
+@pytest.mark.parametrize("row_order", (True, False),
+                         ids=("row_order", "gathered"))
+def test_fcfs_campaign_schedule_has_no_gather_or_loop(one_chip, row_order):
+    """On a make_jobset table the FCFS campaign's scheduling pass compiles
+    to neither a gather nor a while loop; the same table without
+    ``submit_sorted`` keeps both, which shows the match finds them."""
+    scn = Scenario(trace=WorkflowTrace(
+        kind="montage_campaign", seed=0,
+        params=(("images", 40), ("mosaics", 2), ("pairs", 110),
+                ("tiles", 4))), total_nodes=32, policy="fcfs")
+    _, ops = _schedule_ops(scn, one_chip, row_order)
+    found = {op for op, _, _ in ops} & {"gather", "while"}
+    assert found == (set() if row_order else {"gather", "while"})
+
+
+@pytest.mark.parametrize("row_order", (True, False),
+                         ids=("row_order", "gathered"))
+def test_easy_phase_a_has_no_table_sized_gather(one_chip, row_order):
+    """EASY's phase A on a make_jobset table reads the queue without a
+    J-sized gather (the backfill window never had one)."""
+    scn = Scenario(trace=SyntheticTrace(n_jobs=256, seed=0, kind="sdsc_sp2"),
+                   total_nodes=128, policy="backfill")
+    J, ops = _schedule_ops(scn, one_chip, row_order)
+    sized = [name for op, shape, name in ops
+             if op == "gather" and re.match(rf"\w+\[{J}\]", shape)]
+    assert bool(sized) != row_order, sized
